@@ -25,11 +25,12 @@ from repro.core import (
 )
 from repro.data.pipeline import make_sharded_streams
 from repro.federated import mesh_cooperative_update, mesh_federated_train
+from repro.launch.mesh import make_mesh
 
 
 def run(n_hidden: int = 64, steps: int = 200, seed: int = 0) -> dict:
     n_dev = len(jax.devices())
-    mesh = jax.make_mesh((n_dev,), ("data",))
+    mesh = make_mesh((n_dev,), ("data",))
     ds = normalized_dataset("har", seed=seed)
     streams = make_sharded_streams(ds, n_dev, steps + 2 * n_hidden, seed=seed)
     ecfg = edge_config("har")

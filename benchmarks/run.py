@@ -65,6 +65,9 @@ def main() -> None:
              "previous history entry",
     )
     args = ap.parse_args()
+    from repro.launch.cache import enable_compile_cache
+
+    enable_compile_cache()
 
     names = [name for name, _ in BENCHES]
     if args.only and args.only not in names:

@@ -12,10 +12,12 @@ controller + degraded ladder + write-ahead log) in front of a resident
     shed overflow policy; asserts shedding engages but stays bounded,
     queue depth never exceeds capacity, and accepted == acked.
   - **crash**  — a child process serves durable traffic (snapshots +
-    WAL) and is SIGKILLed mid-soak; the parent recovers in-process:
-    newest snapshot + WAL replay must reproduce the child's recorded
-    per-tick digests bit-for-bat (tick-identical), telemetry counters
-    stay continuous, and the recovered front-end serves fresh traffic.
+    WAL) and is SIGKILLed mid-soak; a second child recovers: newest
+    snapshot + WAL replay must reproduce the victim's recorded per-tick
+    digests bit-for-bit (tick-identical), telemetry counters stay
+    continuous, and the recovered front-end serves fresh traffic. Both
+    children run on the CPU: the leg is about process death, not the
+    accelerator, and a parent that has touched jax holds the chip.
   - **degraded** — injected worker stalls drive the ladder up
     (skip-merge vetoes governor rounds, shed rejects ingress) and calm
     ticks drive it back down to NORMAL with merges resumed.
@@ -27,8 +29,8 @@ the build.
     PYTHONPATH=src python benchmarks/serve_ingress.py [--smoke]
 
 ``--smoke`` IS the acceptance configuration; the full run soaks the
-steady leg longer. ``--child <dir>`` is internal (the crash leg's
-victim process).
+steady leg longer. ``--child <dir>`` and ``--recover <dir>`` are
+internal (the crash leg's victim and recovery processes).
 """
 from __future__ import annotations
 
@@ -321,37 +323,56 @@ def _tick_then_maybe_die(tick_fn, batch, kw, runtime: FleetRuntime):
     return rep
 
 
-def run_crash(workdir: Path) -> dict:
-    # a stale workdir (earlier run's snapshots past this run's kill
-    # tick) would restore a future tick and break the replay compare
-    shutil.rmtree(workdir, ignore_errors=True)
-    workdir.mkdir(parents=True, exist_ok=True)
-    reports = workdir / "reports.jsonl"
-    env = dict(os.environ)
+def _crash_child(flag: str, workdir: Path) -> subprocess.Popen:
+    env = dict(os.environ, JAX_PLATFORMS="cpu")
     root = Path(__file__).resolve().parents[1]
     env["PYTHONPATH"] = str(root / "src") + os.pathsep + env.get("PYTHONPATH", "")
-    proc = subprocess.Popen(
-        [sys.executable, str(Path(__file__).resolve()), "--child", str(workdir)],
+    return subprocess.Popen(
+        [sys.executable, str(Path(__file__).resolve()), flag, str(workdir)],
         cwd=root, env=env,
-        stdout=subprocess.DEVNULL, stderr=subprocess.STDOUT,
+        stdout=subprocess.DEVNULL, stderr=subprocess.PIPE,
     )
+
+
+def _wait(proc: subprocess.Popen) -> tuple[int, str]:
     try:
-        # the child soaks past CRASH_KILL_AT_TICK and SIGKILLs itself
-        # mid-snapshot-window; SIGKILL = no cleanup, no graceful drain
-        rc = proc.wait(timeout=300)
-        assert rc == -signal.SIGKILL, f"child exited rc={rc}, not SIGKILL"
+        _, err = proc.communicate(timeout=300)
     finally:
         if proc.poll() is None:
             proc.kill()
             proc.wait(timeout=30)
+    return proc.returncode, (err or b"").decode()[-2000:]
 
+
+def run_crash(workdir: Path) -> dict:
+    """Victim child (SIGKILLed mid-soak), then a recovery child that
+    replays and compares; returns the recovery child's report."""
+    # a stale workdir (earlier run's snapshots past this run's kill
+    # tick) would restore a future tick and break the replay compare
+    shutil.rmtree(workdir, ignore_errors=True)
+    workdir.mkdir(parents=True, exist_ok=True)
+    # the child soaks past CRASH_KILL_AT_TICK and SIGKILLs itself
+    # mid-snapshot-window; SIGKILL = no cleanup, no graceful drain
+    rc, err = _wait(_crash_child("--child", workdir))
+    assert rc == -signal.SIGKILL, f"child exited rc={rc}, not SIGKILL: {err}"
+    rc, err = _wait(_crash_child("--recover", workdir))
+    assert rc == 0, f"recovery child failed rc={rc}: {err}"
+    return json.loads((workdir / "crash.json").read_text())
+
+
+def recover_main(workdir: str) -> None:
+    """Crash-leg recovery: restore the victim's newest snapshot, replay
+    its WAL, hold the replayed digests to the victim's, serve fresh
+    traffic, and write the leg's report to ``crash.json``."""
+    workdir = Path(workdir)
+    reports = workdir / "reports.jsonl"
     child_digests = [
         json.loads(line) for line in reports.read_text().splitlines() if line
     ]
     child_by_tick = {d["tick"]: d for d in child_digests}
     last_child_tick = max(child_by_tick)
 
-    # ---- recover in-process: snapshot restore + WAL replay
+    # ---- snapshot restore + WAL replay
     runtime, frontend = _crash_frontend(workdir, seed=0)
     replay_digests: list[dict] = []
     _digest_wrap(runtime, replay_digests)
@@ -390,7 +411,7 @@ def run_crash(workdir: Path) -> dict:
 
     acks = asyncio.run(fresh())
     assert all(a.ok for a in acks), {a.status for a in acks}
-    return {
+    report = {
         "n_devices": CRASH_DEVICES,
         "snapshot_every": CRASH_SNAPSHOT_EVERY,
         "child_ticks": last_child_tick + 1,
@@ -401,6 +422,7 @@ def run_crash(workdir: Path) -> dict:
         "fresh_requests_ok": len(acks),
         "post_recovery_ticks": runtime.tick_no,
     }
+    (workdir / "crash.json").write_text(json.dumps(report))
 
 
 # ----------------------------------------------------------------- degraded
@@ -582,10 +604,14 @@ if __name__ == "__main__":
     ap.add_argument("--smoke", action="store_true",
                     help="CI soak — this IS the acceptance configuration")
     ap.add_argument("--child", default=None, help=argparse.SUPPRESS)
+    ap.add_argument("--recover", default=None, help=argparse.SUPPRESS)
     ap.add_argument("--out", default="BENCH_serve_ingress.json")
     args = ap.parse_args()
     if args.child is not None:
         child_main(args.child)
+        sys.exit(0)
+    if args.recover is not None:
+        recover_main(args.recover)
         sys.exit(0)
     for line in main(args.out, smoke=args.smoke):
         print(line)

@@ -44,16 +44,23 @@ class UV(NamedTuple):
         return int(self.u.size * self.u.dtype.itemsize + self.v.size * self.v.dtype.itemsize)
 
 
+def uv_of(p: jnp.ndarray, beta: jnp.ndarray, *, ridge: float = 0.0) -> UV:
+    """Eq. 15 on a bare (P, β) pair: U = P⁻¹, V = Uβ."""
+    u = invert_u(p, ridge=ridge)
+    u = 0.5 * (u + u.T)  # P is SPD in exact arithmetic; re-symmetrize f32 noise
+    # full f32: at DEFAULT precision a TPU runs this as one bf16 pass,
+    # and the merge solve amplifies that error by κ(U)
+    v = jnp.matmul(u, beta, precision=jax.lax.Precision.HIGHEST)
+    return UV(u=u, v=v)
+
+
 def to_uv(state: OSELMState, *, ridge: float = 0.0) -> UV:
     """Eq. 15: U = P⁻¹, V = Uβ.
 
     Only computed when results are shipped (the paper notes there is no
     need to maintain U,V per input chunk).
     """
-    u = invert_u(state.p, ridge=ridge)
-    u = 0.5 * (u + u.T)  # P is SPD in exact arithmetic; re-symmetrize f32 noise
-    v = u @ state.beta
-    return UV(u=u, v=v)
+    return uv_of(state.p, state.beta, ridge=ridge)
 
 
 def uv_add(a: UV, b: UV) -> UV:

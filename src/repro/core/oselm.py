@@ -92,7 +92,7 @@ def oselm_step_k1(
     t: jnp.ndarray,
     *,
     kernel: bool = False,
-    interpret: bool = True,
+    interpret: bool | None = None,
 ) -> OSELMState:
     """k=1 fast path (paper's deployed configuration).
 
@@ -100,7 +100,7 @@ def oselm_step_k1(
     ``t`` are single samples shaped (n,) and (m,). ``kernel=True`` runs
     the step through the fused Pallas kernels
     (``repro.kernels.ops.oselm_step_k1_kernel``: hidden_proj +
-    matmul_atb + rank1_add; interpret=True on CPU) — same dispatch
+    matmul_atb + rank1_add; Mosaic on TPU, the interpreter elsewhere) — same dispatch
     convention as ``fleet_train(kernel=True)``.
     """
     if kernel:

@@ -1,15 +1,10 @@
-"""jax version compatibility for shard_map manual-axes code.
+"""shard_map helpers shared by every manual-axes user in the repo
+(``federated.mesh_federation``, ``fleet.sharded``).
 
-Two API shifts are bridged for every shard_map user in the repo
-(``federated.mesh_federation``, ``fleet.sharded``):
-
-- ``shard_map`` moved from ``jax.experimental.shard_map`` to ``jax``
-  proper in jax 0.6.
-- jax >= 0.6 tracks varying manual axes: psum outputs are
-  device-invariant and must be re-varied (``jax.lax.pvary``) before
-  flowing out through a sharded out_spec or back into a device-varying
-  scan carry. Older jax (<= 0.4.x) has neither ``jax.typeof`` nor
-  ``pvary`` and doesn't track variance, so the re-vary is a no-op.
+jax tracks which manual mesh axes a value varies over: psum outputs are
+device-invariant and must be re-varied (``jax.lax.pcast(...,
+to="varying")``) before flowing out through a sharded out_spec or back
+into a device-varying scan carry.
 """
 from __future__ import annotations
 
@@ -18,36 +13,19 @@ from typing import Sequence
 import jax
 import jax.numpy as jnp
 
-try:  # jax >= 0.6
-    from jax import shard_map as _shard_map_impl
-except ImportError:  # pragma: no cover - older jax
-    from jax.experimental.shard_map import shard_map as _shard_map_impl
-
-HAS_VARYING_TYPES = hasattr(jax, "typeof") and hasattr(jax.lax, "pvary")
-
 
 def shard_map_compat(f, mesh, in_specs, out_specs, check_rep=True):
-    """``check_rep=False`` is needed for bodies containing ops with no
-    replication rule (e.g. ``pallas_call``); jax >= 0.6 renamed the
-    kwarg to ``check_vma``, so the flag is translated per version."""
-    kw = {}
-    if not check_rep:
-        import inspect
-
-        params = inspect.signature(_shard_map_impl).parameters
-        if "check_vma" in params:  # jax >= 0.6
-            kw["check_vma"] = False
-        elif "check_rep" in params:
-            kw["check_rep"] = False
-    return _shard_map_impl(
-        f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, **kw
+    """``jax.shard_map`` over ``mesh``. ``check_rep=False`` turns off the
+    varying-axes check (``check_vma``), which bodies containing ops with
+    no replication rule (e.g. ``pallas_call``) need."""
+    return jax.shard_map(
+        f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
+        check_vma=check_rep,
     )
 
 
 def revary(x: jnp.ndarray, axes: Sequence[str]) -> jnp.ndarray:
     """Re-vary a device-invariant value (e.g. a psum output) over
-    ``axes``; identity on jax without varying-type tracking."""
-    if not HAS_VARYING_TYPES:
-        return x
+    ``axes``."""
     missing = tuple(a for a in axes if a not in jax.typeof(x).vma)
-    return jax.lax.pvary(x, missing) if missing else x
+    return jax.lax.pcast(x, missing, to="varying") if missing else x

@@ -124,10 +124,18 @@ class EdgeDevice:
         server.upload(Payload.from_uv(self.device_id, uv, self.version))
 
     def merge_from(self, server: FederationServer, peer_ids: Iterable[str]) -> None:
-        """Steps 3–5: download demanded peers, add (Eq. 8), recover (Eq. 6)."""
-        merged = to_uv(self.state)
+        """Steps 3–5: download demanded peers, add (Eq. 8), recover (Eq. 6).
+
+        The payloads are added in device-id order, so every device of a
+        round solves the same f32 sum: another order per device leaves
+        their merged models apart by up to κ(ΣU)·ε."""
+        parts = {self.device_id: to_uv(self.state)}
         for pid in peer_ids:
-            merged = uv_add(merged, server.download(pid, exclude=self.device_id).to_uv())
+            parts[pid] = server.download(pid, exclude=self.device_id).to_uv()
+        ids = sorted(parts)
+        merged = parts[ids[0]]
+        for pid in ids[1:]:
+            merged = uv_add(merged, parts[pid])
         self.state = from_uv(self.state, merged)
 
 

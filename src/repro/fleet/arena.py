@@ -44,7 +44,8 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core import OSELMState, init_oselm, init_slfn
-from repro.core.elm import SLFNParams, invert_u
+from repro.core.e2lm import uv_of
+from repro.core.elm import SLFNParams
 from repro.fleet.comm import payload_nbytes
 from repro.fleet.fleet import _solve_uv
 from repro.fleet.sharded import cohort_tree_reduce
@@ -444,11 +445,12 @@ class CohortMerger:
         self.topology = topology
         self.schedule = schedule
         self.ridge = float(ridge)
-        on_tpu = jax.default_backend() == "tpu"
         if kernel == "auto":
-            kernel = on_tpu
+            from repro.kernels.fleet_ingest import native_kernels
+
+            kernel = native_kernels()
         self.kernel = bool(kernel)
-        self.interpret = (not on_tpu) if interpret is None else interpret
+        self.interpret = interpret  # None: decided by platform
         self.mesh = mesh
         self.mesh_axes = tuple(mesh_axes)
         self._jits: dict = {}
@@ -493,10 +495,8 @@ class CohortMerger:
     # -- payload math shared by every mode: the resident fleet_to_uv,
     # minus the per-device basis (a page's basis is the one shared copy)
     def _w_of(self, p, beta):
-        u = jax.vmap(lambda pp: invert_u(pp, ridge=self.ridge))(p)
-        u = 0.5 * (u + jnp.swapaxes(u, -1, -2))
-        v = u @ beta
-        return jnp.concatenate([u, v], axis=-1)
+        uv = jax.vmap(lambda pp, bb: uv_of(pp, bb, ridge=self.ridge))(p, beta)
+        return jnp.concatenate([uv.u, uv.v], axis=-1)
 
     def _page_partial_fn(self, local_cids: np.ndarray, n_segments: int):
         """Jitted tier-1 partial: (page p, β, mask) → (n_segments, Ñ,

@@ -182,11 +182,8 @@ def fleet_from_uv(
             v=jnp.where(ok[:, None, None], uv.v, jnp.zeros_like(uv.v)),
         )
     else:
-        try:
-            ok_np = np.asarray(ok)
-        except (jax.errors.ConcretizationTypeError,
-                jax.errors.TracerArrayConversionError):
-            ok_np = None  # traced — eager check not possible here
+        # traced (inside a jit): the eager check is not possible here
+        ok_np = None if isinstance(ok, jax.core.Tracer) else np.asarray(ok)
         if ok_np is not None and not ok_np.all():
             raise ValueError(
                 "non-finite merged (U, V) for devices "
@@ -209,11 +206,7 @@ def _solve_uv(u: jnp.ndarray, v: jnp.ndarray, ridge: float, nonfinite: str = "er
         u = jnp.where(ok, u, jnp.eye(u.shape[-1], dtype=u.dtype))
         v = jnp.where(ok, v, jnp.zeros_like(v))
     else:
-        try:
-            concrete = bool(ok)
-        except jax.errors.ConcretizationTypeError:
-            concrete = None
-        if concrete is False:
+        if not isinstance(ok, jax.core.Tracer) and not bool(ok):
             raise ValueError(
                 "non-finite (U, V) reached the §4.2 solve — reject the "
                 "corrupt payload upstream or pass nonfinite='repair'"
@@ -349,15 +342,15 @@ def fleet_merge_kernel(
     topology: Topology,
     *,
     ridge: float = 0.0,
-    interpret: bool = True,
+    interpret: bool | None = None,
     payload_precision: str = "f32",
 ) -> OSELMState:
     """``fleet_merge`` on the Pallas merge-kernel family: the stacked
     [U | V] payload is mixed by the sparsity-aware kernels and solved by
     the fused Gauss-Jordan kernel (``repro.kernels.topology_merge``);
     on the open ring the mix and solve are ONE kernel, so merged (U, V)
-    never round-trips through HBM. ``interpret=True`` runs on CPU;
-    pass False on TPU to lower via Mosaic. ``payload_precision`` applies
+    never round-trips through HBM. The platform picks the lowering
+    (Mosaic on TPU, the interpreter elsewhere). ``payload_precision`` applies
     the one-shot wire codec (f16 cast or the fused Pallas
     ``quantize_pack`` for int8) before the mix."""
     uv = fleet_to_uv(states, ridge=ridge)
@@ -521,7 +514,7 @@ def fleet_merge_masked_kernel(
     mask: jnp.ndarray,
     *,
     ridge: float = 0.0,
-    interpret: bool = True,
+    interpret: bool | None = None,
     payload_precision: str = "f32",
 ) -> OSELMState:
     """``fleet_merge_masked`` through the Pallas merge-kernel family:
@@ -602,7 +595,7 @@ def fleet_merge_quantized(
     mask: jnp.ndarray | None = None,
     fp_mask: jnp.ndarray | None = None,
     kernel: bool = False,
-    interpret: bool = True,
+    interpret: bool | None = None,
 ) -> tuple[OSELMState, jnp.ndarray | None]:
     """The stateful lossy merge round: every participating device
     publishes its error-feedback-compensated quantized payload, the

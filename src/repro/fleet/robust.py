@@ -221,7 +221,7 @@ def robust_merge_from_w(
     ridge: float,
     *,
     kernel: bool = False,
-    interpret: bool = True,
+    interpret: bool | None = None,
     receive: jnp.ndarray | None = None,
 ) -> tuple[OSELMState, jnp.ndarray]:
     """Robust participation-masked merge of published payloads ``w``
@@ -313,7 +313,7 @@ def fleet_merge_robust(
     mask: jnp.ndarray | None = None,
     ridge: float = 0.0,
     kernel: bool = False,
-    interpret: bool = True,
+    interpret: bool | None = None,
 ) -> tuple[OSELMState, jnp.ndarray]:
     """``fleet_merge_masked`` with bounded Byzantine influence: clip,
     trim, score. Returns ``(merged_states, outlier_scores)``.

@@ -191,6 +191,15 @@ def fleet_merge_sharded(
     sums + per-device solves. Returns the merged fleet with the same
     sharding.
     """
+    fn, operands = _sharded_merge_program(topology, mesh, tuple(axes), ridge)
+    return fn(states, *operands)
+
+
+def _sharded_merge_program(
+    topology: Topology, mesh: Mesh, axes: tuple[str, ...], ridge: float
+) -> tuple:
+    """The jitted shard_map program of one sharded merge, and the
+    operands it takes after the fleet."""
     n_shards = _mesh_axis_size(mesh, axes)
     if topology.n_devices % n_shards:
         raise ValueError(
@@ -211,7 +220,7 @@ def fleet_merge_sharded(
                 "shards — use fewer shards or the single-process merge"
             )
         fn = _cached_sharded_jit(
-            ("halo", mesh, tuple(axes), topology, ridge),
+            ("halo", mesh, axes, topology, ridge),
             lambda: _shard_map(
                 lambda st: _halo_ring_merge_body(
                     st, axes[0], n_shards, topology.hops, ridge
@@ -219,7 +228,7 @@ def fleet_merge_sharded(
                 mesh, in_specs=(spec,), out_specs=spec,
             ),
         )
-        return fn(states)
+        return fn, ()
 
     cids, n_clusters, isolated = _merge_cids(topology)
 
@@ -228,8 +237,8 @@ def fleet_merge_sharded(
         uv = fleet_to_uv(st, ridge=ridge)  # this shard's devices only
         su = jax.ops.segment_sum(uv.u, cids_local, num_segments=n_clusters)
         sv = jax.ops.segment_sum(uv.v, cids_local, num_segments=n_clusters)
-        su = jax.lax.psum(su, tuple(axes))  # O(clusters) per-shard collective
-        sv = jax.lax.psum(sv, tuple(axes))
+        su = jax.lax.psum(su, axes)  # O(clusters) per-shard collective
+        sv = jax.lax.psum(sv, axes)
         if isolated:
             pc, betac = jax.vmap(lambda u, v: _solve_uv(u, v, ridge))(su, sv)
             p, beta = pc[cids_local], betac[cids_local]
@@ -242,10 +251,10 @@ def fleet_merge_sharded(
         )
 
     fn = _cached_sharded_jit(
-        ("merge", mesh, tuple(axes), topology, ridge),
+        ("merge", mesh, axes, topology, ridge),
         lambda: _shard_map(body, mesh, in_specs=(spec, spec), out_specs=spec),
     )
-    return fn(states, jnp.asarray(cids))
+    return fn, (jnp.asarray(cids),)
 
 
 # ------------------------------------------- inter-cohort tier-2 reduction

@@ -2,7 +2,8 @@
 
 Layout per kernel: ``<name>.py`` holds the pallas_call + BlockSpec,
 ``ops.py`` the jit'd wrappers, ``ref.py`` the pure-jnp oracles the
-tests assert against (interpret=True on CPU; Mosaic on TPU).
+tests assert against. Each kernel lowers through Mosaic on a TPU and
+runs in the Pallas interpreter elsewhere (``resolve_interpret``).
 """
 from repro.kernels.flash_attn import flash_attention
 from repro.kernels.fleet_ingest import (

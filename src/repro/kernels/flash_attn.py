@@ -25,6 +25,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels.fleet_ingest import resolve_interpret
+
 NEG_INF = -1e30
 
 
@@ -83,7 +85,7 @@ def flash_attention(
     causal: bool = True,
     cq: int = 128,
     ck: int = 128,
-    interpret: bool = True,
+    interpret: bool | None = None,
 ) -> jnp.ndarray:
     """Fused flash attention forward (Pallas, VMEM-resident softmax)."""
     b, sq, h, hd = q.shape
@@ -120,6 +122,6 @@ def flash_attention(
             pltpu.VMEM((cq,), jnp.float32),
             pltpu.VMEM((cq, hd), jnp.float32),
         ],
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(qb, kb, vb)
     return out.reshape(b, h, nq * cq, hd).transpose(0, 2, 1, 3)[:, :sq]

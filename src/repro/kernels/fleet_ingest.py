@@ -21,8 +21,8 @@ This module fuses the whole tick into one pass with two lowerings:
   state touches HBM once per tick instead of once per sample. Sample
   slots padded up to the sublane tile are masked to exact identity
   (they never update P/β and contribute nothing to the score).
-  ``interpret=True`` on CPU, Mosaic on TPU — same convention as
-  ``kernels/topology_merge.py``.
+  Mosaic on TPU, the interpreter elsewhere (``resolve_interpret``, the
+  one decision every kernel in this package defers to).
 
 - ``fleet_ingest_xla`` — the same one-pass dataflow lowered through
   XLA for backends without Pallas execution (this container's CPU):
@@ -58,8 +58,10 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.activations import get_activation
+from repro.core.elm import cho_factor
 from repro.core.oselm import OSELMState
 
 __all__ = [
@@ -68,7 +70,9 @@ __all__ = [
     "fleet_ingest_paged",
     "fleet_ingest_xla",
     "ingest_padding",
+    "native_kernels",
     "resolve_backend",
+    "resolve_interpret",
     "validate_shared_basis",
 ]
 
@@ -80,16 +84,41 @@ def _pad_up(x: int, mult: int) -> int:
     return -(-x // mult) * mult
 
 
+def native_kernels() -> bool:
+    """Whether the Pallas kernels lower natively here (TPU). Where they
+    do, the runtimes always run the fused ingest and the topology-merge
+    kernels; elsewhere the XLA forms are the fast path."""
+    return jax.default_backend() == "tpu"
+
+
 def resolve_backend(backend: str) -> str:
     """The ONE place the ``"auto"`` ingest dispatch is decided: Pallas
     only where it compiles natively (TPU), the fused XLA form elsewhere.
     Shared by the dispatcher, the padding warning and the sharded
     ingest's check_rep decision so they can never disagree."""
     if backend == "auto":
-        return "pallas" if jax.default_backend() == "tpu" else "xla"
+        return "pallas" if native_kernels() else "xla"
     if backend not in ("pallas", "xla"):
         raise ValueError(f"unknown ingest backend {backend!r}")
     return backend
+
+
+def resolve_interpret(interpret: bool | None = None) -> bool:
+    """The ONE place the Pallas lowering is decided: Mosaic on TPU, the
+    interpreter elsewhere. Every kernel entry point defers here, so a
+    kernel that Mosaic refuses fails the run instead of quietly falling
+    back. An explicit ``False`` is honoured off the chip (compiling for
+    a described TPU from a CPU host); the interpreter is refused on a
+    TPU."""
+    on_tpu = jax.default_backend() == "tpu"
+    if interpret is None:
+        return not on_tpu
+    if interpret and on_tpu:
+        raise ValueError(
+            "the Pallas interpreter was requested on a TPU; pass "
+            "interpret=None so the kernel lowers through Mosaic"
+        )
+    return bool(interpret)
 
 
 def ingest_padding(n_samples: int, block_t: int = 32) -> tuple[int, int]:
@@ -140,84 +169,111 @@ def _shared_basis(states: OSELMState) -> tuple[jnp.ndarray, jnp.ndarray]:
 # ------------------------------------------------------------- pallas kernel
 
 
-def _ingest_kernel(*refs, tied: bool,
-                   t_real: int, t_pad: int, m_real: int, nh_real: int,
-                   nh_rows: int, activation: str, forget: float):
+def _ingest_kernel(*refs, tied: bool, t_real: int, m_real: int, nh_real: int,
+                   activation: str, forget: float):
     """One grid step = ``block_d`` devices' whole tick, VMEM-resident.
 
-    Layouts (B = block_d, TP/NL/ML/NHL lane- or sublane-padded, NHR
+    Layouts (B = block_d, TP sublane-padded, NL/ML/NHL lane-padded, NHR
     sublane-padded): x (B, TP, NL), targets (B, TP, ML) — the x block
-    itself when ``tied`` — α (NL, NHL), bias (1, 1, NHL),
-    P (B, NHR, NHL), β (B, NHR, ML). P/β rows ≥ Ñ and lanes ≥ Ñ
-    (resp. m) are zero and stay zero through every update below.
+    itself when ``tied`` — α (NL, NHL), bias (1, NHL), P (B, NHR, NHL),
+    β (B, NHR, ML), the hidden-projection scratch h (B, TP, NHL). P/β
+    rows ≥ Ñ and lanes ≥ Ñ (resp. m) are zero and stay zero.
+
+    Only the window's hidden projection runs on the MXU (one 2-D
+    matmul at HIGHEST precision). Each rank-1 RLS step is a handful of
+    f32 VPU products and lane/sublane reductions over one device's 2-D
+    (P, β) — no batched matvec and no value slicing, which Mosaic does
+    not lower. A vector moves between its lane and sublane layouts by
+    a reduction against the identity, which is exact (×1, +0).
     """
     if tied:
-        x_ref, a_ref, b_ref, p_ref, be_ref, po_ref, bo_ref, l_ref = refs
+        x_ref, a_ref, b_ref, p_ref, be_ref, po_ref, bo_ref, l_ref, h_ref = refs
+        tt_ref = x_ref
     else:
-        x_ref, tt_ref, a_ref, b_ref, p_ref, be_ref, po_ref, bo_ref, l_ref = refs
-    xb = x_ref[...].astype(jnp.float32)                       # (B, TP, NL)
-    tb = xb if tied else tt_ref[...].astype(jnp.float32)      # (B, TP, ML)
+        (x_ref, tt_ref, a_ref, b_ref, p_ref, be_ref,
+         po_ref, bo_ref, l_ref, h_ref) = refs
+    bd, tp, nl = x_ref.shape
+    nhr, nhl = p_ref.shape[1:]
     g = get_activation(activation)
-    # hidden projection for the WHOLE window: one MXU matmul + epilogue.
+    # hidden projection for all B windows: ONE MXU matmul + epilogue.
     # Lanes ≥ Ñ are masked off — G(0·α + 0) need not be 0 (sigmoid!).
-    h_all = g(
+    h = g(
         jax.lax.dot_general(
-            xb, a_ref[...], (((2,), (0,)), ((), ())),
+            x_ref[...].reshape(bd * tp, nl), a_ref[...],
+            (((1,), (0,)), ((), ())),
+            precision=jax.lax.Precision.HIGHEST,
             preferred_element_type=jnp.float32,
         )
         + b_ref[...]
     )
-    nh_mask = jax.lax.broadcasted_iota(jnp.int32, (1, 1, h_all.shape[2]), 2) < nh_real
-    h_all = jnp.where(nh_mask, h_all, 0.0)                    # (B, TP, NHL)
+    lane = jax.lax.broadcasted_iota(jnp.int32, (1, nhl), 1)
+    h_ref[...] = jnp.where(lane < nh_real, h, 0.0).reshape(bd, tp, nhl)
+    eye = (
+        jax.lax.broadcasted_iota(jnp.int32, (nhr, nhl), 0)
+        == jax.lax.broadcasted_iota(jnp.int32, (nhr, nhl), 1)
+    ).astype(jnp.float32)
 
-    p = p_ref[...].astype(jnp.float32)                        # (B, NHR, NHL)
-    be = be_ref[...].astype(jnp.float32)                      # (B, NHR, ML)
+    def device(b, carry):
+        be0 = be_ref[b]                                          # (NHR, ML)
 
-    # pre-train drift signal: prediction error of the incoming window
-    # under the tick-start β — batched, re-using h_all.
-    e0 = tb - jax.lax.dot_general(
-        h_all[:, :, :nh_rows], be, (((2,), (1,)), ((0,), (0,))),
-        preferred_element_type=jnp.float32,
+        def step(t, c):
+            p, be, sq = c
+            h_row = h_ref[b, pl.ds(t, 1), :]                     # (1, NHL)
+            t_row = tt_ref[b, pl.ds(t, 1), :]                    # (1, ML)
+            h_col = jnp.sum(h_row * eye, axis=1, keepdims=True)  # (NHR, 1)
+            # pre-train drift signal: error under the tick-start β
+            e0 = t_row - jnp.sum(h_col * be0, axis=0, keepdims=True)
+            sq = sq + jnp.sum(e0 * e0, keepdims=True)
+            pf = p / forget
+            ph_col = jnp.sum(pf * h_row, axis=1, keepdims=True)  # P h
+            ph_row = jnp.sum(ph_col * eye, axis=0, keepdims=True)
+            denom = 1.0 + jnp.sum(h_col * ph_col, keepdims=True)
+            p_new = pf - ph_col * ph_row / denom
+            err = t_row - jnp.sum(h_col * be, axis=0, keepdims=True)
+            gain = jnp.sum(p_new * h_row, axis=1, keepdims=True)  # P' h
+            return p_new, be + gain * err, sq
+
+        # padded sample slots are never visited: the loop stops at T
+        p, be, sq = jax.lax.fori_loop(
+            0, t_real, step,
+            (p_ref[b], be0, jnp.zeros((1, 1), jnp.float32)),
+        )
+        po_ref[b] = p
+        bo_ref[b] = be
+        l_ref[b] = jnp.broadcast_to(sq / (t_real * m_real), (1, _LANE))
+        return carry
+
+    jax.lax.fori_loop(0, bd, device, 0)
+
+
+# Of v5e's 16 MiB default scoped VMEM, what one ingest program may
+# plan for; the rest is headroom for Mosaic's own spills.
+_VMEM_BUDGET = 12 * 1024 * 1024
+_MAX_BLOCK_D = 64
+
+
+def _ingest_block_d(d: int, tp: int, nl: int, ml: int, nhr: int, nhl: int,
+                   tied: bool = True) -> int:
+    """Devices per ingest grid program, derived from the VMEM budget:
+    the largest power of two whose double-buffered x/targets, (P, β)
+    in and out, loss rows and hidden scratch fit next to the shared
+    basis and one device's live temporaries."""
+    f32 = 4
+    shared = 2 * f32 * (nl * nhl + nhl)                   # α, bias
+    work = f32 * (6 * nhr * nhl + 5 * nhr * ml)           # one device's step
+    per_dev = f32 * (
+        2 * tp * nl + (0 if tied else 2 * tp * ml)        # x (+ targets)
+        + 4 * nhr * (nhl + ml)                            # P, β in + out
+        + 2 * _LANE + tp * nhl                            # losses, h scratch
     )
-    t_mask = jax.lax.broadcasted_iota(jnp.int32, (1, t_pad, 1), 1) < t_real
-    loss = jnp.sum(jnp.where(t_mask, e0 * e0, 0.0), axis=(1, 2)) / (t_real * m_real)
-
-    def body(t, carry):
-        p, be = carry
-        h = jax.lax.dynamic_slice_in_dim(h_all, t, 1, axis=1)[:, 0, :]  # (B, NHL)
-        tt = jax.lax.dynamic_slice_in_dim(tb, t, 1, axis=1)[:, 0, :]    # (B, ML)
-        h_rows = h[:, :nh_rows]                                         # (B, NHR)
-        pf = p / forget
-        ph = jax.lax.dot_general(                                       # P h (rows)
-            pf, h, (((2,), (1,)), ((0,), (0,))),
-            preferred_element_type=jnp.float32,
-        )                                                               # (B, NHR)
-        denom = 1.0 + jnp.sum(h_rows * ph, axis=1, keepdims=True)       # (B, 1)
-        ph_lane = jnp.pad(ph, ((0, 0), (0, h.shape[1] - nh_rows)))      # (B, NHL)
-        p_new = pf - ph[:, :, None] * ph_lane[:, None, :] / denom[:, :, None]
-        err = tt - jax.lax.dot_general(                                 # (B, ML)
-            h_rows, be, (((1,), (1,)), ((0,), (0,))),
-            preferred_element_type=jnp.float32,
+    fit = (_VMEM_BUDGET - shared - work) // per_dev
+    if fit < 1:
+        raise ValueError(
+            f"one device's ingest state (Ñ rows {nhr}, lanes {nhl}, "
+            f"m lanes {ml}, window {tp}) exceeds the {_VMEM_BUDGET} B "
+            "VMEM budget"
         )
-        # gain = P_new h, computed as the reference does (≡ ph/denom in
-        # exact arithmetic; the matvec keeps bit-level drift vs the
-        # sequential oracle inside the 1e-5 parity bound)
-        gain = jax.lax.dot_general(
-            p_new, h, (((2,), (1,)), ((0,), (0,))),
-            preferred_element_type=jnp.float32,
-        )                                                               # (B, NHR)
-        be_new = be + gain[:, :, None] * err[:, None, :]
-        # padded sample slots are exact identity: no update, no λ decay
-        valid = t < t_real
-        return (
-            jnp.where(valid, p_new, p),
-            jnp.where(valid, be_new, be),
-        )
-
-    p, be = jax.lax.fori_loop(0, t_pad, body, (p, be))
-    po_ref[...] = p.astype(po_ref.dtype)
-    bo_ref[...] = be.astype(bo_ref.dtype)
-    l_ref[...] = jnp.broadcast_to(loss[:, None], l_ref.shape).astype(l_ref.dtype)
+    return int(min(1 << (int(fit).bit_length() - 1), _MAX_BLOCK_D, d))
 
 
 @functools.partial(jax.jit, static_argnames=("block_d", "interpret"))
@@ -226,8 +282,8 @@ def fleet_ingest_kernel(
     window: jnp.ndarray,
     targets: jnp.ndarray | None = None,
     *,
-    block_d: int = 8,
-    interpret: bool = True,
+    block_d: int | None = None,
+    interpret: bool | None = None,
 ) -> tuple[OSELMState, jnp.ndarray]:
     """Fused Pallas tick ingest over a stacked fleet.
 
@@ -236,7 +292,8 @@ def fleet_ingest_kernel(
     mean pre-train prediction loss of each device's window — the drift
     signal). Each grid program holds ``block_d`` devices' (P, β) in
     VMEM across the whole window: HBM sees the state once per tick,
-    not once per sample.
+    not once per sample. ``block_d=None`` derives it from the VMEM
+    budget (``_ingest_block_d``).
     """
     window = jnp.asarray(window)
     d, t, n = window.shape
@@ -248,18 +305,19 @@ def fleet_ingest_kernel(
         targets = jnp.asarray(targets)
         assert targets.shape == (d, t, m), (targets.shape, (d, t, m))
 
-    bd = min(block_d, d)
-    dp = _pad_up(d, bd)
     tp = _pad_up(t, _SUBLANE)
     nl = _pad_up(n, _LANE)
     ml = _pad_up(m, _LANE)
     nhl = _pad_up(nh, _LANE)
     nhr = _pad_up(nh, _SUBLANE)
+    bd = (_ingest_block_d(d, tp, nl, ml, nhr, nhl, tied) if block_d is None
+          else min(block_d, d))
+    dp = _pad_up(d, bd)
 
     alpha, bias = _shared_basis(states)
     xw = jnp.pad(window, ((0, dp - d), (0, tp - t), (0, nl - n)))
     ap = jnp.pad(alpha, ((0, nl - n), (0, nhl - nh)))
-    bp = jnp.pad(bias, (0, nhl - nh))[None, None, :]
+    bp = jnp.pad(bias, (0, nhl - nh))[None, :]
     pp = jnp.pad(states.p, ((0, dp - d), (0, nhr - nh), (0, nhl - nh)))
     bep = jnp.pad(states.beta, ((0, dp - d), (0, nhr - nh), (0, ml - m)))
 
@@ -271,14 +329,13 @@ def fleet_ingest_kernel(
     operands += [ap, bp, pp, bep]
     in_specs += [
         pl.BlockSpec((nl, nhl), lambda i: (0, 0)),
-        pl.BlockSpec((1, 1, nhl), lambda i: (0, 0, 0)),
+        pl.BlockSpec((1, nhl), lambda i: (0, 0)),
         pl.BlockSpec((bd, nhr, nhl), lambda i: (i, 0, 0)),
         pl.BlockSpec((bd, nhr, ml), lambda i: (i, 0, 0)),
     ]
 
     kern = functools.partial(
-        _ingest_kernel, tied=tied,
-        t_real=t, t_pad=tp, m_real=m, nh_real=nh, nh_rows=nhr,
+        _ingest_kernel, tied=tied, t_real=t, m_real=m, nh_real=nh,
         activation=states.activation, forget=states.forget,
     )
     p_out, b_out, l_out = pl.pallas_call(
@@ -288,20 +345,21 @@ def fleet_ingest_kernel(
         out_specs=[
             pl.BlockSpec((bd, nhr, nhl), lambda i: (i, 0, 0)),
             pl.BlockSpec((bd, nhr, ml), lambda i: (i, 0, 0)),
-            pl.BlockSpec((bd, _LANE), lambda i: (i, 0)),
+            pl.BlockSpec((bd, 1, _LANE), lambda i: (i, 0, 0)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((dp, nhr, nhl), jnp.float32),
             jax.ShapeDtypeStruct((dp, nhr, ml), jnp.float32),
-            jax.ShapeDtypeStruct((dp, _LANE), jnp.float32),
+            jax.ShapeDtypeStruct((dp, 1, _LANE), jnp.float32),
         ],
-        interpret=interpret,
+        scratch_shapes=[pltpu.VMEM((bd, tp, nhl), jnp.float32)],
+        interpret=resolve_interpret(interpret),
     )(*operands)
     new_states = states.replace(
         p=p_out[:d, :nh, :nh].astype(states.p.dtype),
         beta=b_out[:d, :nh, :m].astype(states.beta.dtype),
     )
-    return new_states, l_out[:d, 0]
+    return new_states, l_out[:d, 0, 0]
 
 
 # --------------------------------------------------------- fused XLA lowering
@@ -362,7 +420,7 @@ def fleet_ingest_xla(
         php = jnp.einsum("dtn,dnm->dtm", hw, pl_)       # H̃ P/λ^c
         s = jnp.einsum("dtn,dun->dtu", php, hw)
         s = s + jnp.eye(bt, dtype=s.dtype)
-        cho = jax.scipy.linalg.cho_factor(s)
+        cho = cho_factor(s)
         gain = jax.scipy.linalg.cho_solve(cho, php)     # S⁻¹ H̃ P/λ^c
         p_new = pl_ - jnp.einsum("dtn,dtm->dnm", php, gain)
         # β' = β + P' Hᵀ W E₀
@@ -408,7 +466,6 @@ def fleet_ingest_paged(
     activation: str = "sigmoid",
     forget: float = 1.0,
     backend: str = "auto",
-    block_d: int = 8,
     block_t: int = 32,
     interpret: bool | None = None,
 ) -> tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
@@ -435,7 +492,7 @@ def fleet_ingest_paged(
     )
     trained, losses = fleet_ingest(
         states, window, backend=backend,
-        block_d=block_d, block_t=block_t, interpret=interpret,
+        block_t=block_t, interpret=interpret,
     )
     return trained.p, trained.beta, losses
 
@@ -446,7 +503,6 @@ def fleet_ingest(
     targets: jnp.ndarray | None = None,
     *,
     backend: str = "auto",
-    block_d: int = 8,
     block_t: int = 32,
     interpret: bool | None = None,
 ) -> tuple[OSELMState, jnp.ndarray]:
@@ -456,17 +512,14 @@ def fleet_ingest(
     fused Woodbury lowering, ``"auto"`` picks Pallas only where it
     compiles natively (TPU) and the XLA form elsewhere — both are the
     same dataflow and match the sequential reference (tests bound
-    both). ``interpret=None`` resolves per backend: Mosaic
-    (interpret=False) on TPU, the Pallas interpreter on CPU — so the
-    runtime's kernel ingest lowers natively on the hardware it was
-    built for without a config knob.
+    both). ``interpret=None`` resolves per platform
+    (``resolve_interpret``): Mosaic on TPU, the Pallas interpreter on
+    CPU.
     """
     validate_shared_basis(states)  # no-op when already under a trace
     backend = resolve_backend(backend)
     if backend == "pallas":
-        if interpret is None:
-            interpret = jax.default_backend() != "tpu"
         return fleet_ingest_kernel(
-            states, window, targets, block_d=block_d, interpret=interpret,
+            states, window, targets, interpret=interpret,
         )
     return fleet_ingest_xla(states, window, targets, block_t=block_t)

@@ -24,6 +24,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels.fleet_ingest import resolve_interpret
+
 
 def _gla_kernel(q_ref, k_ref, v_ref, la_ref, o_ref, s_scr, *, nc: int):
     ci = pl.program_id(1)
@@ -77,7 +79,7 @@ def gla_forward(
     log_a: jnp.ndarray,    # (B, S, H)
     *,
     chunk: int = 128,
-    interpret: bool = True,
+    interpret: bool | None = None,
 ) -> jnp.ndarray:
     """Fused chunked GLA forward. Padding tokens get log_a = 0 and
     zeroed k/v so the carried state is unaffected."""
@@ -115,6 +117,6 @@ def gla_forward(
         out_specs=pl.BlockSpec((1, c, dv), lambda bh, ci: (bh, ci, 0)),
         out_shape=jax.ShapeDtypeStruct((b * h, nc * c, dv), q.dtype),
         scratch_shapes=[pltpu.VMEM((dk, dv), jnp.float32)],
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(qb, kb, vb, lab)
     return out.reshape(b, h, nc * c, dv).transpose(0, 2, 1, 3)[:, :s]

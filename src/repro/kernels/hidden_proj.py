@@ -18,6 +18,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.activations import get_activation
+from repro.kernels.fleet_ingest import resolve_interpret
 
 
 def _hidden_kernel(x_ref, a_ref, b_ref, o_ref, acc_ref, *, activation: str, nk: int):
@@ -47,7 +48,7 @@ def hidden_proj(
     bm: int = 128,
     bn: int = 128,
     bk: int = 128,
-    interpret: bool = True,
+    interpret: bool | None = None,
 ) -> jnp.ndarray:
     """H = G(x·α + b) for x:(M,K), α:(K,N), b:(N,) → (M,N) f32.
 
@@ -74,6 +75,6 @@ def hidden_proj(
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, kk: (i, j)),
         out_shape=jax.ShapeDtypeStruct((mp, np_), jnp.float32),
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(xp, ap, bp)
     return out[:m, :n]

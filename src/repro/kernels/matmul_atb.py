@@ -18,6 +18,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels.fleet_ingest import resolve_interpret
+
 
 def _atb_kernel(a_ref, b_ref, o_ref, acc_ref, *, nk: int):
     @pl.when(pl.program_id(2) == 0)
@@ -42,7 +44,7 @@ def matmul_atb(
     bi: int = 128,
     bj: int = 128,
     bk: int = 128,
-    interpret: bool = True,
+    interpret: bool | None = None,
 ) -> jnp.ndarray:
     """AᵀB for A:(K,N1), B:(K,N2) → (N1,N2) f32 (K = samples)."""
     k, n1 = a.shape
@@ -65,13 +67,13 @@ def matmul_atb(
         out_specs=pl.BlockSpec((bi, bj), lambda i, j, kk: (i, j)),
         out_shape=jax.ShapeDtypeStruct((n1p, n2p), jnp.float32),
         scratch_shapes=[pltpu.VMEM((bi, bj), jnp.float32)],
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(ap, bp)
     return out[:n1, :n2]
 
 
 def uv_accum(
-    h: jnp.ndarray, t: jnp.ndarray, *, interpret: bool = True
+    h: jnp.ndarray, t: jnp.ndarray, *, interpret: bool | None = None
 ) -> tuple[jnp.ndarray, jnp.ndarray]:
     """U = HᵀH, V = Hᵀt in one pass each (paper Eq. 6 intermediates)."""
     u = matmul_atb(h, h, interpret=interpret)

@@ -1,7 +1,7 @@
 """jit'd public wrappers over the Pallas kernels.
 
-``interpret`` defaults to True (this container is CPU-only; on TPU pass
-interpret=False and the same pallas_calls lower via Mosaic). The fused
+``interpret=None`` lets the platform decide (``resolve_interpret``:
+Mosaic on TPU, the Pallas interpreter elsewhere). The fused
 k=1 OS-ELM step composes three kernels:
 
     1. hidden_proj   h  = G(x·α + b)                 (MXU matmul + epilogue)
@@ -35,7 +35,7 @@ __all__ = [
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
 def oselm_step_k1_kernel(
-    state: OSELMState, x: jnp.ndarray, t: jnp.ndarray, *, interpret: bool = True
+    state: OSELMState, x: jnp.ndarray, t: jnp.ndarray, *, interpret: bool | None = None
 ) -> OSELMState:
     """Kernelized Eq. 12 k=1 step — drop-in for `core.oselm.oselm_step_k1`."""
     h = hidden_proj(
@@ -60,7 +60,7 @@ def uv_from_batch_kernel(
     t: jnp.ndarray,
     *,
     activation: str = "sigmoid",
-    interpret: bool = True,
+    interpret: bool | None = None,
 ):
     """Batched E²LM statistics straight from raw data:
     H = G(xα+b); U = HᵀH; V = Hᵀt — the ELM/E²LM training front half."""
@@ -68,7 +68,7 @@ def uv_from_batch_kernel(
     return uv_accum(h, t, interpret=interpret)
 
 
-def uv_from_state_kernel(state: OSELMState, x: jnp.ndarray, *, interpret: bool = True):
+def uv_from_state_kernel(state: OSELMState, x: jnp.ndarray, *, interpret: bool | None = None):
     """Autoencoder variant (t = x)."""
     return uv_from_batch_kernel(
         state.params.alpha, state.params.bias, x, x,

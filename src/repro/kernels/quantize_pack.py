@@ -20,7 +20,7 @@ kernel fuses the whole publish into one VMEM-resident pass per device:
 
 int8 outputs use the (32, 128) Mosaic minimum tile (f32 uses (8, 128)),
 so rows are padded to 32. The in-kernel concat splits at the unaligned
-column Ñ; that relayout is free under ``interpret=True`` (CPU CI) and
+column Ñ; that relayout is free in the interpreter (CPU CI) and
 acceptable on Mosaic because the whole payload is register/VMEM
 resident. ``quantize_pack_xla`` is the bit-identical XLA reference the
 CPU parity tests pin against (same reduction/round/clip semantics as
@@ -41,6 +41,7 @@ from repro.fleet.quantize import (
     n_col_tiles,
     quantize_tiles,
 )
+from repro.kernels.fleet_ingest import resolve_interpret
 
 __all__ = ["quantize_pack", "quantize_pack_xla"]
 
@@ -84,7 +85,7 @@ def quantize_pack(
     v: jnp.ndarray,
     residual: jnp.ndarray | None = None,
     *,
-    interpret: bool = True,
+    interpret: bool | None = None,
 ) -> tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
     """Fused publish step for a stacked fleet: u (D, Ñ, Ñ), v (D, Ñ, m),
     residual (D, Ñ, Ñ+m) or None → ``(codes int8 (D, Ñ, Ñ+m),
@@ -121,7 +122,7 @@ def quantize_pack(
             jax.ShapeDtypeStruct((d, 1, _LANE), jnp.float32),
             jax.ShapeDtypeStruct((d, rp, cp), jnp.float32),
         ],
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(up, vp, rs)
     return (
         codes[:, :n, : n + m],

@@ -19,6 +19,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.kernels.fleet_ingest import resolve_interpret
+
 
 def _rank1_kernel(x_ref, u_ref, v_ref, s_ref, o_ref):
     scale = s_ref[0, 0]
@@ -38,7 +40,7 @@ def rank1_add(
     *,
     bi: int = 256,
     bj: int = 256,
-    interpret: bool = True,
+    interpret: bool | None = None,
 ) -> jnp.ndarray:
     """O = X + scale·u vᵀ for X:(N1,N2), u:(N1,), v:(N2,) → f32."""
     n1, n2 = x.shape
@@ -61,6 +63,6 @@ def rank1_add(
         ],
         out_specs=pl.BlockSpec((bi, bj), lambda i, j: (i, j)),
         out_shape=jax.ShapeDtypeStruct((n1p, n2p), jnp.float32),
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(xp, up, vp, s)
     return out[:n1, :n2]

@@ -44,6 +44,7 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels.fleet_ingest import resolve_interpret
 from repro.kernels.topology_merge import _LANE, _pad_stacked
 
 __all__ = [
@@ -116,7 +117,7 @@ def robust_segment_sum_mix(
     n_clusters: int,
     trim: int,
     *,
-    interpret: bool = True,
+    interpret: bool | None = None,
 ) -> tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
     """Clipped/trimmed masked cluster aggregates.
 
@@ -152,7 +153,7 @@ def _robust_segment_sum_mix_call(
     n_clusters: int,
     trim: int,
     *,
-    interpret: bool = True,
+    interpret: bool | None = None,
 ) -> tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
     d, r, c = x.shape
     xp, rp, cp = _pad_stacked(x)
@@ -171,7 +172,7 @@ def _robust_segment_sum_mix_call(
         functools.partial(_robust_segsum_kernel, trim=trim),
         grid_spec=grid_spec,
         out_shape=[shape, shape, shape],
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(cluster_ids, mask, scale, xp)
     return tot[:, :r, :c], lo[:, :r, :c], hi[:, :r, :c]
 

@@ -26,14 +26,15 @@ exploits the adjacency structure directly:
   Gauss-Jordan sweep per device over the augmented system
   [U+εI | I | V] → [I | P | β] held entirely in VMEM/registers, giving
   P and β in a single kernel (no separate Cholesky factor + two
-  triangular solves round-tripping through HBM). Elimination without
-  pivoting is stable here because U+εI is SPD.
+  triangular solves round-tripping through HBM). U+εI is SPD, so no
+  pivoting is needed; the sweep runs on the unit-diagonal scaled
+  system, without which f32 loses 3-4 digits (``_gj_solve``).
 - ``banded_merge_solve`` — the fully fused hot path: neighbor-sum AND
   solve in ONE kernel invocation per device, so the merged (U, V)
   never exists in HBM at all.
 
-All paths run under ``interpret=True`` on CPU (this container) and
-lower via Mosaic on TPU, same pattern as ``kernels/ops.py``.
+All paths lower via Mosaic on TPU and run in the Pallas interpreter
+elsewhere (``resolve_interpret``), same pattern as ``kernels/ops.py``.
 ``fleet_merge_kernel`` dispatches a whole stacked ``OSELMState`` merge;
 cluster-level solving (one solve per cluster instead of per device when
 the merged models are provably identical) comes from
@@ -50,6 +51,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.fleet.topology import Topology
+from repro.kernels.fleet_ingest import resolve_interpret
 
 __all__ = [
     "banded_mix",
@@ -93,7 +95,7 @@ def _banded_kernel(x_ref, o_ref, acc_ref, *, n_off: int):
 
 
 @functools.partial(jax.jit, static_argnames=("hops", "interpret"))
-def banded_mix(x: jnp.ndarray, hops: int, *, interpret: bool = True) -> jnp.ndarray:
+def banded_mix(x: jnp.ndarray, hops: int, *, interpret: bool | None = None) -> jnp.ndarray:
     """Circular banded neighbor-sum out[d] = Σ_{o=-hops..hops} x[(d+o)%D].
 
     Requires 2·hops+1 ≤ D (a wider band double-counts; that regime is
@@ -112,7 +114,7 @@ def banded_mix(x: jnp.ndarray, hops: int, *, interpret: bool = True) -> jnp.ndar
         out_specs=pl.BlockSpec((1, rp, _LANE), lambda i, j, o: (i, 0, j)),
         out_shape=jax.ShapeDtypeStruct((d, rp, cp), jnp.float32),
         scratch_shapes=[pltpu.VMEM((1, rp, _LANE), jnp.float32)],
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(xp)
     return out[:, :r, :c]
 
@@ -137,7 +139,7 @@ def _segsum_kernel(cids_ref, x_ref, o_ref, acc_ref):
 
 
 def segment_sum_mix(
-    x: jnp.ndarray, cluster_ids, n_clusters: int, *, interpret: bool = True
+    x: jnp.ndarray, cluster_ids, n_clusters: int, *, interpret: bool | None = None
 ) -> jnp.ndarray:
     """Cluster aggregates (C, R, Cc) = segment_sum(x, cluster_ids).
 
@@ -158,7 +160,7 @@ def segment_sum_mix(
 
 @functools.partial(jax.jit, static_argnames=("n_clusters", "interpret"))
 def _segment_sum_mix_call(
-    x: jnp.ndarray, cluster_ids: jnp.ndarray, n_clusters: int, *, interpret: bool = True
+    x: jnp.ndarray, cluster_ids: jnp.ndarray, n_clusters: int, *, interpret: bool | None = None
 ) -> jnp.ndarray:
     d, r, c = x.shape
     xp, rp, cp = _pad_stacked(x)
@@ -173,7 +175,7 @@ def _segment_sum_mix_call(
         _segsum_kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((n_clusters, rp, cp), jnp.float32),
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(jnp.asarray(cluster_ids, jnp.int32), xp)
     return out[:, :r, :c]
 
@@ -202,7 +204,7 @@ def masked_segment_sum_mix(
     mask: jnp.ndarray,
     n_clusters: int,
     *,
-    interpret: bool = True,
+    interpret: bool | None = None,
 ) -> jnp.ndarray:
     """Participation-masked cluster aggregates: out[c] = Σ_{d: cid[d]=c}
     mask[d]·x[d]. Same contiguous-cluster requirement as
@@ -228,7 +230,7 @@ def _masked_segment_sum_mix_call(
     mask: jnp.ndarray,
     n_clusters: int,
     *,
-    interpret: bool = True,
+    interpret: bool | None = None,
 ) -> jnp.ndarray:
     d, r, c = x.shape
     xp, rp, cp = _pad_stacked(x)
@@ -243,7 +245,7 @@ def _masked_segment_sum_mix_call(
         _masked_segsum_kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((n_clusters, rp, cp), jnp.float32),
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(cluster_ids, mask, xp)
     return out[:, :r, :c]
 
@@ -254,7 +256,7 @@ def _gather_kernel(cids_ref, s_ref, o_ref):
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
 def segment_broadcast(
-    cluster_sums: jnp.ndarray, cluster_ids: jnp.ndarray, *, interpret: bool = True
+    cluster_sums: jnp.ndarray, cluster_ids: jnp.ndarray, *, interpret: bool | None = None
 ) -> jnp.ndarray:
     """Gather each device's cluster aggregate back: out[d] = sums[cid[d]]."""
     d = cluster_ids.shape[0]
@@ -270,7 +272,7 @@ def segment_broadcast(
         _gather_kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((d, rp, cp), jnp.float32),
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(jnp.asarray(cluster_ids, jnp.int32), sp)
     return out[:, :r, :c]
 
@@ -300,7 +302,7 @@ def dense_mix(
     bi: int = 128,
     bj: int = 128,
     bk: int = 128,
-    interpret: bool = True,
+    interpret: bool | None = None,
 ) -> jnp.ndarray:
     """Tiled out = M @ flatten(x) for an arbitrary (D, D) mask — the
     dense baseline the sparse paths are measured against."""
@@ -321,7 +323,7 @@ def dense_mix(
         out_specs=pl.BlockSpec((bi, bj), lambda i, j, kk: (i, j)),
         out_shape=jax.ShapeDtypeStruct((dp_i, fp), jnp.float32),
         scratch_shapes=[pltpu.VMEM((bi, bj), jnp.float32)],
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(mp, xfp)
     return out[:d, :f].reshape(d, r, c)
 
@@ -330,7 +332,7 @@ def dense_mix(
 
 
 def topology_mix(
-    x: jnp.ndarray, topology: Topology, *, interpret: bool = True
+    x: jnp.ndarray, topology: Topology, *, interpret: bool | None = None
 ) -> jnp.ndarray:
     """Kernel equivalent of ``Topology.mix`` on a stacked (D, R, C)
     array — same dispatch, Pallas execution."""
@@ -368,10 +370,27 @@ def _gj_sweep(w: jnp.ndarray, n: int, rows: jnp.ndarray, cols: jnp.ndarray):
     return jax.lax.fori_loop(0, n, body, w)
 
 
+def _gj_solve(w: jnp.ndarray, n: int, rows: jnp.ndarray, cols: jnp.ndarray):
+    """[A | I | V] → [I | A⁻¹ | A⁻¹V], eliminating on the Jacobi-scaled
+    system S·A·S (S = diag(A)^-½, unit diagonal) and scaling back.
+
+    Unscaled, f32 elimination without pivoting loses 3-4 digits on the
+    merged Grams Eq. 8 produces (a dominant all-ones direction from
+    sigmoid features, diagonals ~10⁵): β off by 1e-2 relative at
+    κ(A) = 25, where the Cholesky reference holds 1e-6. Scaled, the
+    sweep matches it. Row-scaling the augmented block turns the I
+    block into S, so the sweep leaves (SAS)⁻¹S and (SAS)⁻¹SV; one more
+    row scaling gives A⁻¹ and A⁻¹V."""
+    diag = jnp.where(rows == cols, w, 0.0)
+    s_row = jnp.where(rows < n, jax.lax.rsqrt(jnp.sum(diag, axis=1, keepdims=True)), 1.0)
+    s_col = jnp.where(cols < n, jax.lax.rsqrt(jnp.sum(diag, axis=0, keepdims=True)), 1.0)
+    return _gj_sweep(w * s_row * s_col, n, rows, cols) * s_row
+
+
 def _solve_kernel(w_ref, o_ref, *, n: int, n_p: int, w_p: int):
     rows = jax.lax.broadcasted_iota(jnp.int32, (n_p, 1), 0)
     cols = jax.lax.broadcasted_iota(jnp.int32, (1, w_p), 1)
-    o_ref[0] = _gj_sweep(w_ref[0], n, rows, cols)
+    o_ref[0] = _gj_solve(w_ref[0], n, rows, cols)
 
 
 def _augment(u: jnp.ndarray, v: jnp.ndarray, ridge: float, n_p: int, w_p: int):
@@ -397,7 +416,7 @@ def from_uv_solve(
     v: jnp.ndarray,
     *,
     ridge: float = 0.0,
-    interpret: bool = True,
+    interpret: bool | None = None,
 ) -> tuple[jnp.ndarray, jnp.ndarray]:
     """Batched §4.2 step 5 over the leading device axis: ridge-add +
     solve fused into one Gauss-Jordan kernel per device, returning
@@ -414,7 +433,7 @@ def from_uv_solve(
         in_specs=[pl.BlockSpec((1, n_p, w_p), lambda i: (i, 0, 0))],
         out_specs=pl.BlockSpec((1, n_p, w_p), lambda i: (i, 0, 0)),
         out_shape=jax.ShapeDtypeStruct((dn, n_p, w_p), jnp.float32),
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(w)
     return out[:, :n, n_p : n_p + n], out[:, :n, n_p + n : n_p + n + m]
 
@@ -449,7 +468,7 @@ def _banded_solve_kernel(*refs, n: int, n_p: int, w_p: int, n_off: int, ridge: f
     v_blk = wsum[:, n_p:]
     w = jnp.concatenate([a, eye_blk, v_blk], axis=1)
     w = jnp.pad(w, ((0, 0), (0, w_p - w.shape[1])))
-    w = _gj_sweep(w, n, rows, cols)
+    w = _gj_solve(w, n, rows, cols)
     m = v_blk.shape[1]
     p_ref[0] = jnp.pad(w[:, n_p : n_p + n], ((0, 0), (0, p_ref.shape[-1] - n)))
     b_ref[0] = jnp.pad(w[:, n_p + n : n_p + n + m], ((0, 0), (0, b_ref.shape[-1] - m)))
@@ -461,7 +480,7 @@ def banded_merge_solve(
     hops: int,
     *,
     ridge: float = 0.0,
-    interpret: bool = True,
+    interpret: bool | None = None,
 ) -> tuple[jnp.ndarray, jnp.ndarray]:
     """The fused ring hot path: ``w`` is the stacked [U | V] payload
     (D, Ñ, Ñ+m) — Ñ is read off the row dimension; one kernel
@@ -502,6 +521,6 @@ def banded_merge_solve(
             jax.ShapeDtypeStruct((d, n_p, p_cols), jnp.float32),
             jax.ShapeDtypeStruct((d, n_p, b_cols), jnp.float32),
         ],
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(*([wp] * n_off))
     return p_out[:, :n, :n], b_out[:, :n, :m]

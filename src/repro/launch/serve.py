@@ -46,8 +46,7 @@ def main() -> None:
         from repro.launch.serve_fleet import main as fleet_main
 
         sys.argv.remove("--fleet")
-        fleet_main()
-        return
+        sys.exit(fleet_main())
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="gemma3-1b")
     ap.add_argument("--reduced", action="store_true", default=True)

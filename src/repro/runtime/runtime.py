@@ -63,7 +63,7 @@ from repro.fleet.robust import (
 )
 from repro.fleet.staleness import StalenessSchedule, _lagged_gather
 from repro.fleet.topology import Topology
-from repro.kernels.fleet_ingest import fleet_ingest
+from repro.kernels.fleet_ingest import fleet_ingest, native_kernels
 from repro.obs import TelemetryConfig, TelemetrySink
 from repro.runtime.detector import (
     DetectorConfig,
@@ -79,7 +79,12 @@ logger = logging.getLogger(__name__)
 
 @dataclasses.dataclass(frozen=True)
 class RuntimeConfig:
-    """Static configuration of one resident fleet runtime."""
+    """Static configuration of one resident fleet runtime.
+
+    ``use_merge_kernel`` and ``use_ingest_kernel`` are off-chip test
+    switches: on a TPU both kernel families always run (Mosaic), so the
+    fields only choose, elsewhere, between plain XLA and the Pallas
+    interpreter."""
 
     topology: Topology
     ridge: float = 1e-3
@@ -88,13 +93,15 @@ class RuntimeConfig:
     gate_merges: bool = True          # False: no-quarantine baseline (everyone merges)
     staleness: StalenessSchedule | None = None
     use_merge_kernel: bool = False    # route merges through the Pallas family
+                                      # (always on a TPU)
     payload_precision: str = "f32"    # merge wire format ("f32" | "f16" | "int8");
                                       # non-f32 runs the error-feedback codec with
                                       # the detector-gated precision policy:
                                       # quarantine-risk devices ship f32 payloads,
                                       # stable devices the quantized format
                                       # (repro.fleet.quantize / detector.quarantine_risk)
-    use_ingest_kernel: bool = False   # fused tick ingest (repro.kernels.fleet_ingest)
+    use_ingest_kernel: bool = False   # fused tick ingest (repro.kernels.fleet_ingest;
+                                      # always on a TPU)
     ingest_backend: str = "auto"      # "pallas" | "xla" | "auto" (TPU→pallas)
     snapshot_every: int | None = None
     snapshot_dir: str | Path | None = None
@@ -239,8 +246,12 @@ class FleetRuntime:
 
         det_cfg = config.detector
         topology, ridge = config.topology, config.ridge
+        # on a TPU the tick always runs the fused ingest and the
+        # topology-merge kernels (Mosaic); elsewhere the flags choose
+        native = native_kernels()
+        use_merge_kernel = config.use_merge_kernel or native
 
-        if config.use_ingest_kernel:
+        if config.use_ingest_kernel or native:
             from repro.kernels.fleet_ingest import validate_shared_basis
 
             # the tick is jitted (tracers inside), so the kernel-ingest
@@ -303,9 +314,9 @@ class FleetRuntime:
                 # traced operands, so precision gating never retraces
                 return _quantized_merge_body(
                     fleet, topology, residual, precision, ridge,
-                    mask, fp_mask, config.use_merge_kernel, True,
+                    mask, fp_mask, use_merge_kernel, None,
                 )
-        elif config.use_merge_kernel:
+        elif use_merge_kernel:
             def merge_fresh(fleet, mask):
                 return fleet_merge_masked_kernel(fleet, topology, mask, ridge=ridge)
         else:
@@ -326,7 +337,7 @@ class FleetRuntime:
         self._last_good = None
         if hardened:
             robust_cfg = config.robust
-            use_kernel = config.use_merge_kernel
+            use_kernel = use_merge_kernel
 
             def merge_boundary(fleet, mask, receive, mult, noise, nonfin, last_good):
                 uv = fleet_to_uv(fleet, ridge=ridge)
@@ -342,7 +353,7 @@ class FleetRuntime:
                     # robust arm is proven against
                     if use_kernel:
                         merged = _masked_kernel_merge_from_w(
-                            fleet, topology, mask, w, ridge, True
+                            fleet, topology, mask, w, ridge, None
                         )
                     else:
                         merged = _masked_merge_body(
@@ -358,7 +369,7 @@ class FleetRuntime:
                 new_last = jnp.where(finite[:, None, None], w, last_good)
                 merged, scores = robust_merge_from_w(
                     fleet, topology, mask, w_pub, robust_cfg, ridge,
-                    kernel=use_kernel, interpret=True, receive=receive,
+                    kernel=use_kernel, receive=receive,
                 )
                 return merged, new_last, scores, finite
 

@@ -162,7 +162,8 @@ os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
 import jax, jax.numpy as jnp, numpy as np
 from repro.core import init_oselm, init_slfn, oselm_train_sequential, to_uv, cooperative_update
 from repro.federated import mesh_cooperative_update
-mesh = jax.make_mesh((8,), ("data",))
+from repro.launch.mesh import make_mesh
+mesh = make_mesh((8,), ("data",))
 params = init_slfn(jax.random.PRNGKey(0), 24, 12)
 states, xs = [], []
 for s in range(8):
@@ -199,7 +200,8 @@ from repro.fleet import (init_fleet, fleet_train, fleet_merge, fleet_merge_shard
                          star, hierarchical, all_to_all)
 from repro.launch.sharding import shard_fleet
 
-mesh = jax.make_mesh((8,), ("data",))
+from repro.launch.mesh import make_mesh
+mesh = make_mesh((8,), ("data",))
 D, H, F = 32, 8, 24
 key = jax.random.PRNGKey(0)
 x_init = jax.random.uniform(key, (D, 2 * H, F))
@@ -239,7 +241,8 @@ from repro.fleet import (init_fleet, fleet_train, fleet_train_sharded,
                          fleet_merge, fleet_merge_sharded, ring)
 from repro.launch.sharding import shard_fleet
 
-mesh = jax.make_mesh((8,), ("data",))
+from repro.launch.mesh import make_mesh
+mesh = make_mesh((8,), ("data",))
 D, H, F, T = 32, 8, 24, 16
 key = jax.random.PRNGKey(0)
 x_init = jax.random.uniform(key, (D, 2 * H, F))

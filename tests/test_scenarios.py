@@ -117,13 +117,19 @@ GOLDEN_SIZES = {
     "har": dict(n_devices=12, ticks=80),
     "mnist_like": dict(),                   # preset size (D=16)
 }
+# har and mnist_like were re-locked under jax's default
+# (threefry-partitionable) PRNG stream, which draws a different SLFN
+# basis from key_seed=0 than the stream they were first locked under:
+# har merged 0.8179 -> 0.8327 and one clean device crosses its band
+# once (fp 0 -> 1); mnist_like local 0.6367 -> 0.6529, merged 0.7608 ->
+# 0.7980, clean 0.8185 -> 0.8712, delay 1.75 -> 1.5 ticks.
 GOLDEN = {
     "driving": dict(local=1.0000, merged=1.0000, clean=1.0000, merges=5,
                     delay=0.0, missed=0, fp=0, events=2),
-    "har": dict(local=0.8535, merged=0.8179, clean=1.0000, merges=5,
-                delay=0.0, missed=0, fp=0, events=3),
-    "mnist_like": dict(local=0.6367, merged=0.7608, clean=0.8185, merges=5,
-                       delay=1.75, missed=0, fp=0, events=4),
+    "har": dict(local=0.8521, merged=0.8327, clean=1.0000, merges=5,
+                delay=0.0, missed=0, fp=1, events=3),
+    "mnist_like": dict(local=0.6529, merged=0.7980, clean=0.8712, merges=5,
+                       delay=1.5, missed=0, fp=0, events=4),
 }
 AUC_BAND = 0.03
 DELAY_BAND = 1.0
