@@ -53,7 +53,7 @@ if __package__ in (None, ""):  # `python benchmarks/serve_ingress.py` from repo 
 
 from benchmarks.history import record_and_gate
 from repro.fleet import init_fleet, ring
-from repro.obs import TelemetryConfig
+from repro.obs import TelemetryConfig, spans_between
 from repro.runtime import FleetRuntime, GovernorConfig, RuntimeConfig
 from repro.serve import (
     AdmissionConfig,
@@ -155,15 +155,25 @@ def run_steady(*, rounds: int, seed: int = 0) -> dict:
             frontend, n_clients=16, outstanding=32, rounds=rounds,
             n_devices=N_DEVICES, seed=seed + 1,
         )
-        wall = time.perf_counter() - t0
+        t1 = time.perf_counter()
         await frontend.stop()
-        return acks, wall
+        return acks, t0, t1
 
-    acks, wall = asyncio.run(drive())
+    acks, t0, t1 = asyncio.run(drive())
+    wall = t1 - t0
     runtime.assert_compile_once()
     ing = runtime.telemetry.summary()["ingress"]
     ok = [a for a in acks if a.ok]
     rps = len(acks) / wall
+    # request latency from the acks themselves (every one, not a window
+    # of samples); admission time from the per-window ingress.close spans
+    latency = np.array([a.latency_s for a in ok])
+    closes = [
+        sp for sp in spans_between(t0, time.perf_counter())
+        if sp.name == "ingress.close" and "n" in sp.attrs
+    ]
+    admit_s = sum(sp.attrs["admit_s"] for sp in closes)
+    admitted = sum(sp.attrs["n"] for sp in closes)
     return {
         "n_devices": N_DEVICES,
         "requests": len(acks),
@@ -173,9 +183,9 @@ def run_steady(*, rounds: int, seed: int = 0) -> dict:
         "rps_ratio": rps / RPS_FLOOR,
         "ticks": runtime.tick_no,
         "merges": runtime.governor.state.merges,
-        "request_p50_us": ing["request_latency"]["p50_s"] * 1e6,
-        "request_p99_us": ing["request_latency"]["p99_s"] * 1e6,
-        "admission_p99_us": ing["admission_latency"]["p99_s"] * 1e6,
+        "request_p50_us": float(np.percentile(latency, 50)) * 1e6,
+        "request_p99_us": float(np.percentile(latency, 99)) * 1e6,
+        "admission_us_per_request": admit_s / max(admitted, 1) * 1e6,
         "tick_p99_us": runtime.telemetry.tick_seconds.quantile(0.99) * 1e6,
         "accepted": ing["accepted"],
         "acked": ing["acked"],

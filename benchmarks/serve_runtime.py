@@ -437,7 +437,7 @@ def check_telemetry_artifacts(tel_dir: str) -> dict:
     for needle in (
         "# TYPE ticks_total counter",
         "# TYPE tick_phase_seconds histogram",
-        'tick_phase_seconds_bucket{phase="ingest",le="+Inf"}',
+        'tick_phase_seconds_bucket{phase="tick.ingest",le="+Inf"}',
         "# TYPE merge_bytes_total counter",
         "# TYPE quarantined_devices gauge",
     ):

@@ -50,6 +50,7 @@ from repro.fleet.comm import payload_nbytes
 from repro.fleet.fleet import _solve_uv
 from repro.fleet.sharded import cohort_tree_reduce
 from repro.fleet.topology import Topology
+from repro.obs import trace
 
 __all__ = [
     "FleetArena",
@@ -424,6 +425,11 @@ class CohortMerger:
     page shape (and, for ``clusters``, per unique local-cluster-id
     pattern); participation masks are traced operands, so governor
     gating never retraces — same contract as the resident merge.
+
+    Each mode records three program spans (``repro.obs.trace``):
+    ``merge.gather`` (the pages in, with their partials), ``merge.solve``
+    and ``merge.fanout`` (the host writes of the merged models into the
+    arena); ``ring`` records them once per page.
     """
 
     def __init__(
@@ -582,48 +588,55 @@ class CohortMerger:
     def _merge_global(self, arena: FleetArena, mask: np.ndarray) -> None:
         zeros = np.zeros(self.schedule.cohort_size, np.int32)
         partial_fn = self._page_partial_fn(zeros, 1)
-        parts = []
-        for lo, hi in self.schedule.bounds():
-            parts.append(partial_fn(
-                jnp.asarray(arena.p[lo:hi]),
-                jnp.asarray(arena.beta[lo:hi]),
-                jnp.asarray(mask[lo:hi], jnp.float32),
-            )[0])
-        total = cohort_tree_reduce(
-            jnp.stack(parts), self.mesh, self.mesh_axes
-        )
-        nh = arena.n_hidden
-        p1, b1 = self._solve_fn(batched=False)(total[:, :nh], total[:, nh:])
-        p1, b1 = np.asarray(p1), np.asarray(b1)
-        for lo, hi in self.schedule.bounds():
-            m = mask[lo:hi]
-            arena.p[lo:hi][m] = p1
-            arena.beta[lo:hi][m] = b1
+        with trace.span("merge.gather"):
+            parts = []
+            for lo, hi in self.schedule.bounds():
+                parts.append(partial_fn(
+                    jnp.asarray(arena.p[lo:hi]),
+                    jnp.asarray(arena.beta[lo:hi]),
+                    jnp.asarray(mask[lo:hi], jnp.float32),
+                )[0])
+            total = cohort_tree_reduce(
+                jnp.stack(parts), self.mesh, self.mesh_axes
+            )
+            jax.block_until_ready(total)
+        with trace.span("merge.solve"):
+            nh = arena.n_hidden
+            p1, b1 = self._solve_fn(batched=False)(total[:, :nh], total[:, nh:])
+            p1, b1 = np.asarray(p1), np.asarray(b1)
+        with trace.span("merge.fanout"):
+            for lo, hi in self.schedule.bounds():
+                m = mask[lo:hi]
+                arena.p[lo:hi][m] = p1
+                arena.beta[lo:hi][m] = b1
 
     def _merge_clusters(self, arena: FleetArena, mask: np.ndarray) -> None:
         nh, m_out = arena.n_hidden, arena.n_out
         acc = np.zeros(
             (self.topology.n_clusters, nh, nh + m_out), np.float32
         )
-        for (lo, hi), (off, local) in zip(
-            self.schedule.bounds(), self._locals
-        ):
-            part = self._page_partial_fn(local, self._k_max)(
-                jnp.asarray(arena.p[lo:hi]),
-                jnp.asarray(arena.beta[lo:hi]),
-                jnp.asarray(mask[lo:hi], jnp.float32),
+        with trace.span("merge.gather"):
+            for (lo, hi), (off, local) in zip(
+                self.schedule.bounds(), self._locals
+            ):
+                part = self._page_partial_fn(local, self._k_max)(
+                    jnp.asarray(arena.p[lo:hi]),
+                    jnp.asarray(arena.beta[lo:hi]),
+                    jnp.asarray(mask[lo:hi], jnp.float32),
+                )
+                k_here = int(local[-1]) + 1
+                acc[off : off + k_here] += np.asarray(part)[:k_here]
+        with trace.span("merge.solve"):
+            pc, bc = self._solve_fn(batched=True)(
+                jnp.asarray(acc[:, :, :nh]), jnp.asarray(acc[:, :, nh:])
             )
-            k_here = int(local[-1]) + 1
-            acc[off : off + k_here] += np.asarray(part)[:k_here]
-        pc, bc = self._solve_fn(batched=True)(
-            jnp.asarray(acc[:, :, :nh]), jnp.asarray(acc[:, :, nh:])
-        )
-        pc, bc = np.asarray(pc), np.asarray(bc)
-        for lo, hi in self.schedule.bounds():
-            m = mask[lo:hi]
-            gcids = self._cids[lo:hi]
-            arena.p[lo:hi][m] = pc[gcids[m]]
-            arena.beta[lo:hi][m] = bc[gcids[m]]
+            pc, bc = np.asarray(pc), np.asarray(bc)
+        with trace.span("merge.fanout"):
+            for lo, hi in self.schedule.bounds():
+                m = mask[lo:hi]
+                gcids = self._cids[lo:hi]
+                arena.p[lo:hi][m] = pc[gcids[m]]
+                arena.beta[lo:hi][m] = bc[gcids[m]]
 
     def _ring_page_fn(self):
         key = ("ring_page",)
@@ -654,20 +667,29 @@ class CohortMerger:
         # pre-merge halo snapshot: each page's window sums must read its
         # neighbors' PRE-merge payloads even after those pages already
         # scattered their merged state back into the arena
-        halos = []
-        for lo, hi in self.schedule.bounds():
-            ids = np.concatenate(
-                [np.arange(lo - hops, lo), np.arange(hi, hi + hops)]
-            ) % d
-            halos.append((
-                arena.p[ids].copy(), arena.beta[ids].copy(), mask[ids].copy()
-            ))
-        for (lo, hi), (hp, hb, hm) in zip(self.schedule.bounds(), halos):
-            p_ext = np.concatenate([hp[:hops], arena.p[lo:hi], hp[hops:]])
-            b_ext = np.concatenate([hb[:hops], arena.beta[lo:hi], hb[hops:]])
-            m_ext = np.concatenate([hm[:hops], mask[lo:hi], hm[hops:]])
-            pc, bc = page_fn(
-                jnp.asarray(p_ext), jnp.asarray(b_ext),
-                jnp.asarray(m_ext, jnp.float32),
-            )
-            arena.write_page(lo, hi, pc, bc, where=mask[lo:hi])
+        with trace.span("merge.gather"):
+            halos = []
+            for lo, hi in self.schedule.bounds():
+                ids = np.concatenate(
+                    [np.arange(lo - hops, lo), np.arange(hi, hi + hops)]
+                ) % d
+                halos.append((
+                    arena.p[ids].copy(), arena.beta[ids].copy(), mask[ids].copy()
+                ))
+        # one page at a time: its halo-extended block in, its banded
+        # solve, its merged rows back into the arena
+        for k, ((lo, hi), (hp, hb, hm)) in enumerate(
+            zip(self.schedule.bounds(), halos)
+        ):
+            with trace.span("merge.gather", page=k):
+                p_ext = np.concatenate([hp[:hops], arena.p[lo:hi], hp[hops:]])
+                b_ext = np.concatenate([hb[:hops], arena.beta[lo:hi], hb[hops:]])
+                m_ext = np.concatenate([hm[:hops], mask[lo:hi], hm[hops:]])
+            with trace.span("merge.solve", page=k):
+                pc, bc = page_fn(
+                    jnp.asarray(p_ext), jnp.asarray(b_ext),
+                    jnp.asarray(m_ext, jnp.float32),
+                )
+                jax.block_until_ready((pc, bc))
+            with trace.span("merge.fanout", page=k):
+                arena.write_page(lo, hi, pc, bc, where=mask[lo:hi])

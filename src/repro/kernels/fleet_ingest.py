@@ -354,6 +354,7 @@ def fleet_ingest_kernel(
         ],
         scratch_shapes=[pltpu.VMEM((bd, tp, nhl), jnp.float32)],
         interpret=resolve_interpret(interpret),
+        name="fleet_ingest_kernel",  # the op name chip traces are read by
     )(*operands)
     new_states = states.replace(
         p=p_out[:d, :nh, :nh].astype(states.p.dtype),

@@ -246,6 +246,7 @@ def _masked_segment_sum_mix_call(
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((n_clusters, rp, cp), jnp.float32),
         interpret=resolve_interpret(interpret),
+        name="_masked_segment_sum_mix_call",  # the op name chip traces show
     )(cluster_ids, mask, xp)
     return out[:, :r, :c]
 
@@ -522,5 +523,6 @@ def banded_merge_solve(
             jax.ShapeDtypeStruct((d, n_p, b_cols), jnp.float32),
         ],
         interpret=resolve_interpret(interpret),
+        name="banded_merge_solve",  # the op name chip traces are read by
     )(*([wp] * n_off))
     return p_out[:, :n, :n], b_out[:, :n, :m]

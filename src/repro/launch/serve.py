@@ -143,7 +143,7 @@ def main() -> None:
 
         t0 = time.time()
         span = (
-            sink.span("serve_round", round=rnd)
+            sink.span("serve_round", seq=rnd)
             if sink is not None else contextlib.nullcontext()
         )
         with span:

@@ -1,10 +1,11 @@
 """repro.obs — zero-dependency fleet telemetry.
 
 Structured metrics (``MetricsRegistry``: typed counters / gauges /
-histograms with labels, Prometheus-style text exposition), span tracing
-(``Tracer``: JSONL trace per run + optional ``jax.profiler``
-annotations), and a crash flight recorder (``FlightRecorder``: bounded
-ring of recent tick records, dumped to ``flight_<tick>.json`` on
+histograms with labels, Prometheus-style text exposition), program
+spans (``repro.obs.trace``: an always-on bounded ring of closed spans
+on ``perf_counter``, each mirrored into a ``jax.profiler`` annotation;
+``Tracer`` writes it as JSONL at flush), and a crash flight recorder
+(``FlightRecorder``: bounded ring of recent tick records, dumped to ``flight_<tick>.json`` on
 exception, non-finite payload rejection, or SLO breach). A
 ``TelemetrySink`` composes the three behind the single export surface
 the runtime, the serving driver, and every benchmark consume.
@@ -20,15 +21,14 @@ from repro.obs.metrics import (
     Gauge,
     Histogram,
     MetricsRegistry,
-    phase_timer,
 )
 from repro.obs.sink import TICK_PHASES, TelemetryConfig, TelemetrySink
-from repro.obs.trace import Tracer
+from repro.obs.trace import Span, SpanRing, Tracer, record, span, spans_between
 
 __all__ = [
-    "Counter", "Gauge", "Histogram", "MetricsRegistry", "phase_timer",
+    "Counter", "Gauge", "Histogram", "MetricsRegistry",
     "LATENCY_BUCKETS_S",
-    "Tracer",
+    "Span", "SpanRing", "Tracer", "record", "span", "spans_between",
     "FlightRecorder", "load_dump",
     "TelemetryConfig", "TelemetrySink", "TICK_PHASES",
 ]

@@ -1,4 +1,4 @@
-"""Typed host-side metrics: counters, gauges, histograms, phase timers.
+"""Typed host-side metrics: counters, gauges, histograms.
 
 The fleet runtime's observable signals — per-tick phase wall-clock,
 merge-round bytes by wire precision, quarantine populations, detector
@@ -23,19 +23,14 @@ Conventions (Prometheus-flavored, but deliberately tiny):
   ``family.labels(phase="merge")`` lazily materializes one child per
   label value. Children are ordinary metrics.
 
-``phase_timer`` wraps one tick phase in a wall-clock measurement with
-an explicit *fence*: the caller hands the phase's output pytree to
-``handle.fence(...)`` and the timer ``block_until_ready``-s it before
-reading the clock, so async dispatch cannot attribute a phase's compute
-to whichever later phase happens to synchronize first.
+Tick phases are timed by the program spans of ``repro.obs.trace``; the
+sink feeds each phase span's duration into its phase histogram.
 """
 from __future__ import annotations
 
 import bisect
-import contextlib
 import json
 import math
-import time
 from collections import deque
 from typing import Callable, Iterable
 
@@ -47,7 +42,6 @@ __all__ = [
     "Histogram",
     "LATENCY_BUCKETS_S",
     "MetricsRegistry",
-    "phase_timer",
 ]
 
 # wall-clock seconds buckets spanning 10 µs .. 10 s (tick phases on CPU
@@ -364,33 +358,3 @@ def _fmt(v: float) -> str:
     if float(v).is_integer() and abs(v) < 1e15:
         return str(int(v))
     return repr(float(v))
-
-
-class _PhaseHandle:
-    """Mutable holder the timed block parks its output pytree in."""
-
-    __slots__ = ("_tree",)
-
-    def __init__(self) -> None:
-        self._tree = None
-
-    def fence(self, tree) -> None:
-        self._tree = tree
-
-
-@contextlib.contextmanager
-def phase_timer(observe: Callable[[float], None]):
-    """Time one phase, fencing whatever the block handed to
-    ``handle.fence(...)`` before the clock is read — jax's async
-    dispatch otherwise bills a phase's compute to the next caller of
-    ``block_until_ready``. ``observe`` receives the fenced seconds."""
-    handle = _PhaseHandle()
-    t0 = time.perf_counter()
-    try:
-        yield handle
-    finally:
-        if handle._tree is not None:
-            import jax
-
-            jax.block_until_ready(handle._tree)
-        observe(time.perf_counter() - t0)

@@ -6,8 +6,8 @@ Owns the three telemetry organs and their output files:
   (phase latencies, merge bytes by precision, quarantine populations,
   detector band dynamics, fault/nonfinite counters — see README
   "Observability" for the full catalog),
-- a ``Tracer`` writing a per-run JSONL trace (optionally mirrored into
-  ``jax.profiler.TraceAnnotation`` scopes),
+- a ``Tracer`` writing the program spans (``repro.obs.trace``) as a
+  per-run JSONL trace when the sink flushes or closes,
 - a ``FlightRecorder`` ring dumped on exception / non-finite payload /
   SLO breach.
 
@@ -26,23 +26,26 @@ from __future__ import annotations
 
 import dataclasses
 import json
+import time
 from pathlib import Path
 
+from repro.obs import trace
 from repro.obs.flight import FlightRecorder
-from repro.obs.metrics import LATENCY_BUCKETS_S, MetricsRegistry, phase_timer
-from repro.obs.trace import Tracer
+from repro.obs.metrics import LATENCY_BUCKETS_S, MetricsRegistry
 
 __all__ = ["TelemetryConfig", "TelemetrySink", "TICK_PHASES"]
 
-# the runtime tick's phase decomposition, in execution order;
-# "quantize" is the host-side precision-policy step of the quantized
-# payload path (the codec itself runs fused inside the merge jit);
-# "page_in"/"page_out" are the cohort-paged runtime's host↔device
-# transfer phases (staging a cohort's arena slice onto the device and
-# writing the updated slice back) — zero for the resident runtime
+# the tick's phase spans, in execution order; each one's duration also
+# lands in tick_phase_seconds{phase=<span name>}. "tick.put" is the
+# resident window's host-to-device copy; the cohort-paged runtime runs
+# "page.stage" (window slice + page puts), "page.wait" (the page's
+# ingest) and "page.store" (page back to the host arena) once per page,
+# inside its "tick.ingest", which also holds "tick.detect";
+# "tick.govern" covers the quantized path's precision policy too
 TICK_PHASES = (
-    "poison", "page_in", "ingest", "page_out", "govern", "quantize",
-    "merge", "snapshot",
+    "tick.poison", "tick.put", "page.stage", "page.wait", "page.store",
+    "tick.ingest", "tick.detect", "tick.readback", "tick.govern",
+    "tick.merge", "tick.telemetry", "tick.snapshot",
 )
 
 # detector band widths / loss ratios are dimensionless O(1) quantities
@@ -60,7 +63,6 @@ class TelemetryConfig:
     max_flight_dumps: int = 4         # total dump budget per run
     slo_tick_seconds: float | None = None  # tick-latency SLO; breach dumps
     trace: bool = True                # write the JSONL span trace
-    profiler_annotations: bool = False  # mirror spans into jax.profiler
     sample_cap: int = 4096            # histogram raw-sample window
     band_sample_every: int = 4        # sample the detector band-width /
                                       # loss-ratio histograms every Nth
@@ -76,9 +78,8 @@ class TelemetrySink:
         cfg = self.config
         self.dir = Path(cfg.dir) if cfg.dir is not None else None
         self.registry = MetricsRegistry()
-        self.tracer = Tracer(
-            self.dir / "trace.jsonl" if (self.dir and cfg.trace) else None,
-            annotations=cfg.profiler_annotations,
+        self.tracer = trace.Tracer(
+            self.dir / "trace.jsonl" if (self.dir and cfg.trace) else None
         )
         self.flight = FlightRecorder(
             cfg.flight_capacity, max_dumps=cfg.max_flight_dumps
@@ -87,7 +88,7 @@ class TelemetrySink:
         r, cap = self.registry, cfg.sample_cap
         self.ticks = r.counter("ticks_total", "serving ticks processed")
         self.phase_seconds = r.histogram(
-            "tick_phase_seconds", "fenced wall-clock per tick phase",
+            "tick_phase_seconds", "wall-clock of each tick phase span",
             labels=("phase",), buckets=LATENCY_BUCKETS_S, sample_cap=cap,
         )
         self.tick_seconds = r.histogram(
@@ -199,15 +200,12 @@ class TelemetrySink:
             "degraded-ladder transitions by target mode",
             labels=("mode",),
         )
-        self.ingress_admission_seconds = r.histogram(
-            "ingress_admission_seconds",
-            "submit-to-admission-decision latency",
-            buckets=LATENCY_BUCKETS_S, sample_cap=cap,
-        )
-        self.ingress_request_seconds = r.histogram(
-            "ingress_request_seconds",
-            "submit-to-ack latency of served requests",
-            buckets=LATENCY_BUCKETS_S, sample_cap=cap,
+        self.ingress_pressure_checks = r.counter(
+            "ingress_pressure_checks_total",
+            "degraded-watchdog checks that saw pressure, by cause (stall: "
+            "a tick past the deadline, p99: tick p99 over the SLO, depth: "
+            "queue depth near capacity)",
+            labels=("cause",),
         )
         # bound observe callables once — phase() sits on the tick path
         self._phase_observe = {
@@ -216,16 +214,16 @@ class TelemetrySink:
 
     # ---------------------------------------------------------------- timing
 
-    def phase(self, name: str):
-        """Context manager timing one tick phase into the phase
-        histogram (``handle.fence(tree)`` fences before the read)."""
+    def phase(self, name: str, **attrs):
+        """The span of one tick phase; its duration also lands in the
+        phase histogram. Fence device work inside it before it closes."""
         observe = self._phase_observe.get(name)
         if observe is None:
             raise ValueError(f"unknown phase {name!r}; have {TICK_PHASES}")
-        return phase_timer(observe)
+        return trace.span(name, observe=observe, **attrs)
 
     def span(self, name: str, **attrs):
-        return self.tracer.span(name, **attrs)
+        return trace.span(name, **attrs)
 
     # --------------------------------------------------------------- flight
 
@@ -239,8 +237,8 @@ class TelemetrySink:
         )
         if path is not None:
             self.flight_dumps.inc()
-            self.tracer.emit({"name": "flight_dump", "tick": int(tick),
-                              "reason": reason, "path": str(path)})
+            now = time.perf_counter()
+            trace.record(f"flight_dump.{reason}", now, now, seq=int(tick))
         return path
 
     # --------------------------------------------------------------- export
@@ -269,18 +267,9 @@ class TelemetrySink:
 
     def ingress_stats(self) -> dict:
         """The serving front-end's view: admission outcomes, queue
-        depth, degraded-ladder position, and submit-to-ack latency."""
-        def _latency(h):
-            if h.count == 0:
-                return None
-            return {
-                "count": h.count,
-                "mean_s": h.sum / h.count,
-                "p50_s": h.quantile(0.50),
-                "p99_s": h.quantile(0.99),
-                "max_s": h.vmax,
-            }
-
+        depth, degraded-ladder position and the watchdog's pressure by
+        cause. Per-request latency is on each ``Ack`` (``latency_s``);
+        per-window host time is in the ``ingress.*`` spans."""
         return {
             "accepted": int(self.ingress_accepted.value),
             "acked": int(self.ingress_acked.value),
@@ -301,8 +290,11 @@ class TelemetrySink:
                 key[0]: int(child.value)
                 for key, child in sorted(self.ingress_transitions.children.items())
             },
-            "admission_latency": _latency(self.ingress_admission_seconds),
-            "request_latency": _latency(self.ingress_request_seconds),
+            "pressure_checks": {
+                key[0]: int(child.value)
+                for key, child in sorted(
+                    self.ingress_pressure_checks.children.items())
+            },
         }
 
     def summary(self) -> dict:

@@ -30,8 +30,10 @@ actual post-merge ticks and its scalar feeds ``detector_update`` via
 the ~(merge_every−1)/merge_every that do not rebase.
 
 Governor, telemetry, and report schema are shared with the resident
-runtime; the paging phases show up as ``page_in``/``page_out`` in the
-phase histograms and the arena/cohort gauges track residency.
+runtime. Each page runs three program spans (``repro.obs.trace``):
+``page.stage`` (window slice and page puts), ``page.wait`` (its ingest
+on the device) and ``page.store`` (page back into the arena), inside the
+tick's ``tick.ingest``; the arena/cohort gauges track residency.
 """
 from __future__ import annotations
 
@@ -50,7 +52,7 @@ from repro.fleet.arena import (
     TierCost,
 )
 from repro.kernels.fleet_ingest import fleet_ingest_paged
-from repro.obs import TelemetrySink
+from repro.obs import TelemetrySink, trace
 from repro.runtime.detector import (
     common_mode_ratio,
     detector_update,
@@ -59,7 +61,6 @@ from repro.runtime.detector import (
 from repro.runtime.feed import TickFeed
 from repro.runtime.governor import MergeDecision, MergeGovernor
 from repro.runtime.runtime import (
-    _NULL_PHASE,
     RuntimeConfig,
     TickReport,
     _where_served,
@@ -192,12 +193,10 @@ class CohortFleetRuntime:
 
     # ------------------------------------------------------------- tick loop
 
-    def _phase(self, name: str):
-        return _NULL_PHASE if self.telemetry is None else self.telemetry.phase(name)
-
-    def _observe_phase(self, name: str, seconds: float) -> None:
-        if self.telemetry is not None:
-            self.telemetry._phase_observe[name](seconds)
+    def _phase(self, name: str, **attrs):
+        if self.telemetry is None:
+            return trace.span(name, **attrs)
+        return self.telemetry.phase(name, **attrs)
 
     def _resolve_batch(self, batch):
         """Normalize the tick's data source to ``fn(lo, hi) -> (C, B, F)``.
@@ -240,6 +239,10 @@ class CohortFleetRuntime:
         Devices in cohorts OUTSIDE this tick's active window report
         NaN losses (they served nothing) and keep model + detector
         state untouched."""
+        with trace.span("tick", seq=self.tick_no) as root:
+            return self._tick(root, batch, served, allow_merge)
+
+    def _tick(self, root, batch, served, allow_merge) -> TickReport:
         t = self.tick_no
         d = self.n_devices
         sched = self.schedule
@@ -255,7 +258,6 @@ class CohortFleetRuntime:
                 )
         active = sched.active(t)
         tel = self.telemetry
-        t_start = time.perf_counter()
 
         # devices actually serving this tick: served ∧ active-cohort
         if len(active) == sched.n_cohorts:
@@ -270,7 +272,7 @@ class CohortFleetRuntime:
         # k's compute is in flight, scatter k back as it lands
         def stage(k: int):
             lo, hi = sched.bounds(k)
-            with self._phase("page_in"):
+            with self._phase("page.stage", page=k):
                 win = np.asarray(batch_fn(lo, hi), np.float32)
                 if win.shape[0] != c or win.ndim != 3 or win.shape[1] < 1:
                     raise ValueError(
@@ -285,46 +287,52 @@ class CohortFleetRuntime:
                     jax.device_put(served_np[lo:hi]),
                 )
 
-        t0 = time.perf_counter()
-        losses_np = np.full(d, np.nan, np.float32)
-        cur = stage(active[0])
-        for i in range(len(active)):
-            lo, hi, pj, bj, wj, sj = cur
-            out = self._ingest(pj, bj, wj, sj)      # async dispatch
-            cur = stage(active[i + 1]) if i + 1 < len(active) else None
-            with self._phase("page_out"):
-                p2, b2, lo_j = out
-                self.arena.p[lo:hi] = np.asarray(p2)     # blocks on page
-                self.arena.beta[lo:hi] = np.asarray(b2)
-                losses_np[lo:hi] = np.asarray(lo_j)
-            if tel is not None:
-                tel.cohort_pages.inc()
+        with self._phase("tick.ingest") as ingest:
+            losses_np = np.full(d, np.nan, np.float32)
+            cur = stage(active[0])
+            for i in range(len(active)):
+                k = active[i]
+                lo, hi, pj, bj, wj, sj = cur
+                out = self._ingest(pj, bj, wj, sj)      # async dispatch
+                cur = stage(active[i + 1]) if i + 1 < len(active) else None
+                # the wait is its own span: the page's ingest on the
+                # device, which the store's first copy would block on
+                with self._phase("page.wait", page=k):
+                    jax.block_until_ready(out)
+                with self._phase("page.store", page=k):
+                    p2, b2, lo_j = out
+                    self.arena.p[lo:hi] = np.asarray(p2)
+                    self.arena.beta[lo:hi] = np.asarray(b2)
+                    losses_np[lo:hi] = np.asarray(lo_j)
+                if tel is not None:
+                    tel.cohort_pages.inc()
 
-        # ---- full-fleet detect (O(D) scalars stay resident). The
-        # common-mode median is fleet-wide state the pages cannot see —
-        # computed here from the PRE-update bank, only on rebase ticks.
-        losses_j = jnp.asarray(losses_np)
-        merge_mask_j = jnp.asarray(self._merge_mask)
-        if self._post_merge:
-            common = self._common(self.det, losses_j, merge_mask_j)
-        else:
-            common = jnp.float32(1.0)  # unused: no device rebases
-        self.det, drifted, fresh = self._detect(
-            self.det, losses_j, jnp.asarray(self._post_merge),
-            merge_mask_j, jnp.asarray(served_eff), common,
-        )
-        jax.block_until_ready((self.det, drifted, fresh))
-        ingest_seconds = time.perf_counter() - t0
-        self._observe_phase("ingest", ingest_seconds)
+            # ---- full-fleet detect (O(D) scalars stay resident). The
+            # common-mode median is fleet-wide state the pages cannot
+            # see — computed here from the PRE-update bank, only on
+            # rebase ticks.
+            with self._phase("tick.detect"):
+                losses_j = jnp.asarray(losses_np)
+                merge_mask_j = jnp.asarray(self._merge_mask)
+                if self._post_merge:
+                    common = self._common(self.det, losses_j, merge_mask_j)
+                else:
+                    common = jnp.float32(1.0)  # unused: no device rebases
+                self.det, drifted, fresh = self._detect(
+                    self.det, losses_j, jnp.asarray(self._post_merge),
+                    merge_mask_j, jnp.asarray(served_eff), common,
+                )
+                jax.block_until_ready((self.det, drifted, fresh))
 
-        drifted_np = np.asarray(drifted)
-        fresh_np = np.asarray(fresh)
-        n_fresh = int(fresh_np.sum())
-        self.detections_total += n_fresh
-        for dev in np.flatnonzero(fresh_np):
-            self.detections.append((t, int(dev)))
+        with self._phase("tick.readback"):
+            drifted_np = np.asarray(drifted)
+            fresh_np = np.asarray(fresh)
+            n_fresh = int(fresh_np.sum())
+            self.detections_total += n_fresh
+            for dev in np.flatnonzero(fresh_np):
+                self.detections.append((t, int(dev)))
 
-        with self._phase("govern"):
+        with self._phase("tick.govern"):
             if self.config.gate_merges:
                 mask = self.governor.participation(drifted_np, losses_np)
             else:
@@ -334,19 +342,19 @@ class CohortFleetRuntime:
         merge_seconds = None
         tier_cost: TierCost | None = None
         if decision.merge:
-            t0 = time.perf_counter()
-            with self._phase("merge"):
+            with self._phase("tick.merge") as merge:
                 tier_cost = self.merger.merge(self.arena, mask)
-            merge_seconds = time.perf_counter() - t0
+            merge_seconds = merge.seconds
             self.merge_round += 1
 
-        tick_seconds = time.perf_counter() - t_start
+        tick_seconds = time.perf_counter() - root.start
         if tel is not None:
-            self._record_telemetry(
-                t, losses_np, drifted_np, fresh_np, n_fresh, decision,
-                tier_cost, ingest_seconds, merge_seconds, tick_seconds,
-                served_eff, len(active),
-            )
+            with self._phase("tick.telemetry"):
+                self._record_telemetry(
+                    t, losses_np, drifted_np, fresh_np, n_fresh, decision,
+                    tier_cost, ingest.seconds, merge_seconds, tick_seconds,
+                    served_eff, len(active),
+                )
 
         self._post_merge = decision.merge
         if decision.merge:
@@ -356,7 +364,7 @@ class CohortFleetRuntime:
         return TickReport(
             tick=t, losses=losses_np, drifted=drifted_np,
             fresh_detections=fresh_np, decision=decision,
-            merge_seconds=merge_seconds, ingest_seconds=ingest_seconds,
+            merge_seconds=merge_seconds, ingest_seconds=ingest.seconds,
             served=None if full else served_eff,
         )
 

@@ -64,7 +64,7 @@ from repro.fleet.robust import (
 from repro.fleet.staleness import StalenessSchedule, _lagged_gather
 from repro.fleet.topology import Topology
 from repro.kernels.fleet_ingest import fleet_ingest, native_kernels
-from repro.obs import TelemetryConfig, TelemetrySink
+from repro.obs import TelemetryConfig, TelemetrySink, trace
 from repro.runtime.detector import (
     DetectorConfig,
     detector_update,
@@ -128,12 +128,13 @@ class TickReport:
     drifted: np.ndarray         # (D,) quarantine flags after detection
     fresh_detections: np.ndarray  # (D,) flags that rose this tick
     decision: MergeDecision
-    merge_seconds: float | None  # wall-clock of the admitted merge (full output
-                                 # pytree fenced), else None
+    merge_seconds: float | None  # the admitted merge's tick.merge span (full
+                                 # output pytree fenced), else None
     robust_scores: np.ndarray | None = None  # (D,) contribution-outlier scores
                                              # of an admitted robust merge round
     nonfinite_payloads: int = 0  # payloads rejected by the finite guard this tick
-    ingest_seconds: float | None = None  # fenced wall-clock of ingest + detect
+    ingest_seconds: float | None = None  # the tick.ingest span: fenced ingest
+                                         # + detect (paged: every page too)
     served: np.ndarray | None = None  # (D,) devices whose batch rows carried
                                       # real (non-padding) samples this tick;
                                       # None = every row (the default path)
@@ -150,26 +151,6 @@ def _where_served(keep: jnp.ndarray, new, old):
         ),
         new, old,
     )
-
-
-class _NullPhase:
-    """Zero-cost stand-in for the telemetry phase timer — the same
-    ``with``/``fence`` surface, nothing measured. One shared instance
-    keeps the telemetry-off tick free of per-phase allocations."""
-
-    __slots__ = ()
-
-    def __enter__(self) -> "_NullPhase":
-        return self
-
-    def __exit__(self, *exc) -> bool:
-        return False
-
-    def fence(self, tree) -> None:
-        pass
-
-
-_NULL_PHASE = _NullPhase()
 
 
 class FleetRuntime:
@@ -426,14 +407,12 @@ class FleetRuntime:
 
     # ------------------------------------------------------------- tick loop
 
-    def _phase(self, name: str):
-        """Phase timer context (a shared no-op when telemetry is off, so
-        the uninstrumented tick pays one attribute check per phase)."""
-        return _NULL_PHASE if self.telemetry is None else self.telemetry.phase(name)
-
-    def _observe_phase(self, name: str, seconds: float) -> None:
-        if self.telemetry is not None:
-            self.telemetry._phase_observe[name](seconds)
+    def _phase(self, name: str, **attrs):
+        """The span of one tick phase (``repro.obs.TICK_PHASES``); with
+        telemetry on, its duration also lands in the phase histogram."""
+        if self.telemetry is None:
+            return trace.span(name, **attrs)
+        return self.telemetry.phase(name, **attrs)
 
     def tick(
         self,
@@ -453,10 +432,13 @@ class FleetRuntime:
         governor's ledger keeps advancing. Both are per-tick operands
         of the compile-once tick function — never a retrace.
 
+        The tick and its phases are program spans (``repro.obs.trace``)
+        under the root span ``tick``, whose ``seq`` is the tick number.
         With telemetry configured an escaping exception dumps the
         flight ring (plus this tick's input batch) before propagating."""
         try:
-            return self._tick(batch, served, allow_merge)
+            with trace.span("tick", seq=self.tick_no) as root:
+                return self._tick(root, batch, served, allow_merge)
         except Exception:
             tel = self.telemetry
             if tel is not None:
@@ -468,6 +450,7 @@ class FleetRuntime:
 
     def _tick(
         self,
+        root,
         batch: np.ndarray,
         served: np.ndarray | None = None,
         allow_merge: bool = True,
@@ -496,8 +479,7 @@ class FleetRuntime:
                 raise ValueError(
                     f"served mask must be ({d},); got {served_np.shape}"
                 )
-        t_start = time.perf_counter()
-        with self._phase("poison"):
+        with self._phase("tick.poison"):
             if injector is not None:
                 # data poisoning attacks through training itself, upstream
                 # of the payload boundary (host-side, before jitted ingest)
@@ -506,29 +488,35 @@ class FleetRuntime:
         # flight dump must carry for the failing tick to be replayable
         self._tick_inputs = batch
 
-        t0 = time.perf_counter()
-        self.states, self.det, losses, drifted, fresh = self._ingest_detect(
-            self.states, self.det, jnp.asarray(batch),
-            jnp.asarray(self._post_merge), jnp.asarray(self._merge_mask),
-            jnp.asarray(served_np),
-        )
-        jax.block_until_ready((self.states, self.det, losses))
-        ingest_seconds = time.perf_counter() - t0
-        self._observe_phase("ingest", ingest_seconds)
+        # the window (and the tick's small operands) to the device,
+        # fenced: nothing else is in flight at this point
+        with self._phase("tick.put"):
+            operands = (
+                jnp.asarray(batch), jnp.asarray(self._post_merge),
+                jnp.asarray(self._merge_mask), jnp.asarray(served_np),
+            )
+            jax.block_until_ready(operands)
 
-        losses_np = np.asarray(losses)
-        drifted_np = np.asarray(drifted)
-        fresh_np = np.asarray(fresh)
-        n_fresh = int(fresh_np.sum())
-        self.detections_total += n_fresh
-        for dev in np.flatnonzero(fresh_np):
-            self.detections.append((t, int(dev)))
+        with self._phase("tick.ingest") as ingest:
+            self.states, self.det, losses, drifted, fresh = self._ingest_detect(
+                self.states, self.det, *operands
+            )
+            jax.block_until_ready((self.states, self.det, losses))
 
-        # detector-gated precision policy: on candidate rounds of a
-        # quantized runtime, quarantine-risk devices are priced (and
-        # shipped) at f32 — computed host-side from the post-update
-        # detector state, like the participation mask
-        with self._phase("quantize"):
+        with self._phase("tick.readback"):
+            losses_np = np.asarray(losses)
+            drifted_np = np.asarray(drifted)
+            fresh_np = np.asarray(fresh)
+            n_fresh = int(fresh_np.sum())
+            self.detections_total += n_fresh
+            for dev in np.flatnonzero(fresh_np):
+                self.detections.append((t, int(dev)))
+
+        with self._phase("tick.govern"):
+            # detector-gated precision policy: on candidate rounds of a
+            # quantized runtime, quarantine-risk devices are priced (and
+            # shipped) at f32 — computed host-side from the post-update
+            # detector state, like the participation mask
             fp_mask = None
             if (
                 self._residual is not None
@@ -537,8 +525,6 @@ class FleetRuntime:
                 fp_mask = np.asarray(
                     quarantine_risk(self.det, self.config.detector)
                 )
-
-        with self._phase("govern"):
             if self.config.gate_merges:
                 mask = self.governor.participation(drifted_np, losses_np)
             else:
@@ -553,52 +539,51 @@ class FleetRuntime:
         robust_scores = None
         nonfinite = 0
         if decision.merge:
-            t0 = time.perf_counter()
-            mask_j = jnp.asarray(mask, jnp.float32)
-            if self._merge_boundary is not None:
-                shape = tuple(self._last_good.shape)
-                if injector is not None:
-                    mult, noise, nonfin = injector.payload_ops(t, shape)
-                else:
-                    mult = np.ones(shape[0], np.float32)
-                    noise = np.zeros(shape, np.float32)
-                    nonfin = np.zeros(shape[0], np.int32)
-                # robust-quarantined devices still DOWNLOAD the merged
-                # model (their payload is distrusted, they are not cut
-                # off) — unless drift-flagged or crashed this tick
-                receive = mask.astype(bool)
-                if self.config.robust is not None:
-                    rq = self.governor.robust_quarantined & ~drifted_np.astype(bool)
+            with self._phase("tick.merge") as merge:
+                mask_j = jnp.asarray(mask, jnp.float32)
+                if self._merge_boundary is not None:
+                    shape = tuple(self._last_good.shape)
                     if injector is not None:
-                        rq = rq & ~injector.crash_mask(t)
-                    receive = receive | rq
-                (self.states, self._last_good, scores_j, finite_j,
-                 ) = self._merge_boundary(
-                    self.states, mask_j, jnp.asarray(receive, jnp.float32),
-                    jnp.asarray(mult), jnp.asarray(noise),
-                    jnp.asarray(nonfin), self._last_good,
-                )
-                fence = (self.states, self._last_good, scores_j, finite_j)
-            elif self.config.staleness is not None:
-                self.states, self._hist_u, self._hist_v = self._merge_stale(
-                    self.states, self._hist_u, self._hist_v, mask_j,
-                    jnp.int32(self.merge_round),
-                )
-                fence = (self.states, self._hist_u, self._hist_v)
-            elif self._residual is not None:
-                self.states, self._residual = self._merge_fresh(
-                    self.states, mask_j, jnp.asarray(fp_mask), self._residual
-                )
-                fence = (self.states, self._residual)
-            else:
-                self.states = self._merge_fresh(self.states, mask_j)
-                fence = self.states
-            # fence the FULL output pytree, not just states.beta — async
-            # dispatch would otherwise bill unfinished ring/residual/score
-            # work to whichever later phase synchronizes first
-            jax.block_until_ready(fence)
-            merge_seconds = time.perf_counter() - t0
-            self._observe_phase("merge", merge_seconds)
+                        mult, noise, nonfin = injector.payload_ops(t, shape)
+                    else:
+                        mult = np.ones(shape[0], np.float32)
+                        noise = np.zeros(shape, np.float32)
+                        nonfin = np.zeros(shape[0], np.int32)
+                    # robust-quarantined devices still DOWNLOAD the merged
+                    # model (their payload is distrusted, they are not cut
+                    # off) — unless drift-flagged or crashed this tick
+                    receive = mask.astype(bool)
+                    if self.config.robust is not None:
+                        rq = self.governor.robust_quarantined & ~drifted_np.astype(bool)
+                        if injector is not None:
+                            rq = rq & ~injector.crash_mask(t)
+                        receive = receive | rq
+                    (self.states, self._last_good, scores_j, finite_j,
+                     ) = self._merge_boundary(
+                        self.states, mask_j, jnp.asarray(receive, jnp.float32),
+                        jnp.asarray(mult), jnp.asarray(noise),
+                        jnp.asarray(nonfin), self._last_good,
+                    )
+                    fence = (self.states, self._last_good, scores_j, finite_j)
+                elif self.config.staleness is not None:
+                    self.states, self._hist_u, self._hist_v = self._merge_stale(
+                        self.states, self._hist_u, self._hist_v, mask_j,
+                        jnp.int32(self.merge_round),
+                    )
+                    fence = (self.states, self._hist_u, self._hist_v)
+                elif self._residual is not None:
+                    self.states, self._residual = self._merge_fresh(
+                        self.states, mask_j, jnp.asarray(fp_mask), self._residual
+                    )
+                    fence = (self.states, self._residual)
+                else:
+                    self.states = self._merge_fresh(self.states, mask_j)
+                    fence = self.states
+                # fence the FULL output pytree, not just states.beta — async
+                # dispatch would otherwise bill unfinished ring/residual/score
+                # work to whichever later phase synchronizes first
+                jax.block_until_ready(fence)
+            merge_seconds = merge.seconds
             if self._merge_boundary is not None:
                 robust_scores = np.asarray(scores_j)
                 nonfinite = int((~np.asarray(finite_j)).sum())
@@ -609,13 +594,14 @@ class FleetRuntime:
         # serving latency of THIS tick: ingest through merge; snapshots
         # amortize across the snapshot_every window and are timed as
         # their own phase below rather than folded into tick_seconds
-        tick_seconds = time.perf_counter() - t_start
+        tick_seconds = time.perf_counter() - root.start
         if self.telemetry is not None:
-            self._record_telemetry(
-                t, batch, losses_np, drifted_np, fresh_np, n_fresh, decision,
-                ingest_seconds, merge_seconds, tick_seconds,
-                robust_scores, nonfinite, served_np,
-            )
+            with self._phase("tick.telemetry"):
+                self._record_telemetry(
+                    t, batch, losses_np, drifted_np, fresh_np, n_fresh,
+                    decision, ingest.seconds, merge_seconds, tick_seconds,
+                    robust_scores, nonfinite, served_np,
+                )
 
         self._post_merge = decision.merge
         if decision.merge:
@@ -626,13 +612,13 @@ class FleetRuntime:
             and self.config.snapshot_every
             and self.tick_no % self.config.snapshot_every == 0
         ):
-            with self._phase("snapshot"):
+            with self._phase("tick.snapshot"):
                 self.snapshot()
         return TickReport(
             tick=t, losses=losses_np, drifted=drifted_np,
             fresh_detections=fresh_np, decision=decision,
             merge_seconds=merge_seconds, robust_scores=robust_scores,
-            nonfinite_payloads=nonfinite, ingest_seconds=ingest_seconds,
+            nonfinite_payloads=nonfinite, ingest_seconds=ingest.seconds,
             served=None if served is None else served_np,
         )
 

@@ -12,14 +12,21 @@ Wires the serving pieces into one fault-tolerant loop:
   ``WriteAheadLog``, and hands it to a single worker thread that runs
   the (blocking, jitted) ``runtime.tick`` off the event loop;
 - a watchdog task folds stall/p99/depth pressure into the
-  ``DegradedLadder`` (skip-merge → stale-scores → shed) and back out;
+  ``DegradedLadder`` (skip-merge → stale-scores → shed) and back out,
+  counting each pressured check by cause;
 - ``recover()`` resumes after a crash: newest runtime snapshot, then
   contiguous WAL replay — the same ticks, bit-identical, so every
   admitted-but-unacked window trains exactly once.
 
 All metrics flow through the runtime's own ``TelemetrySink`` (the
 ingress catalog pre-declared in ``repro.obs.sink``): one registry, one
-snapshot-riding state blob, no forked accounting.
+snapshot-riding state blob, no forked accounting. Host time is recorded
+per window, never per request, as program spans (``repro.obs.trace``)
+whose ``seq`` is the window's tick number: ``ingress.close`` (cutting
+the window; attributes ``n`` requests and ``admit_s``, the admission
+time summed over the submits since the previous window),
+``ingress.queued`` (close to worker pickup) and ``ingress.complete``
+(resolving the window's acks; attribute ``n``).
 """
 from __future__ import annotations
 
@@ -34,6 +41,7 @@ from typing import Callable
 
 import numpy as np
 
+from repro.obs import trace
 from repro.runtime.runtime import FleetRuntime, TickReport
 from repro.serve.admission import (
     ADMIT,
@@ -134,6 +142,7 @@ class ServeFrontend:
         self._last_scores = np.full(d, np.nan, np.float64)
         self._last_drifted = np.zeros(d, bool)
         self._inflight_windows = 0
+        self._admit_s = 0.0  # admission seconds since the last window
         self._tick_started: float | None = None
         self._failed: str | None = None
         self._running = False
@@ -146,6 +155,10 @@ class ServeFrontend:
         self._slots = asyncio.Semaphore(config.max_inflight_windows)
         self._idle = asyncio.Event()
         self._idle.set()
+        self._pressure = {
+            cause: self.telemetry.ingress_pressure_checks.labels(cause=cause)
+            for cause in ("stall", "p99", "depth")
+        }
 
     # ------------------------------------------------------------- lifecycle
 
@@ -225,7 +238,7 @@ class ServeFrontend:
             tick_p99_s=t99.quantile(0.99) if t99.count else None,
             budget_utilization=self.runtime.governor.budget_utilization(),
         )
-        tel.ingress_admission_seconds.observe(time.perf_counter() - t0)
+        self._admit_s += time.perf_counter() - t0
         if verdict == SHED:
             tel.ingress_shed.labels(reason=reason).inc()
             return Ack(req.request_id, "shed", reason=reason)
@@ -288,9 +301,13 @@ class ServeFrontend:
             # backpressure on the runtime itself: never more than
             # max_inflight_windows closed-but-unfinished windows
             await self._slots.acquire()
-            window = self.builder.close(
-                self._seq, allow_merge=self.ladder.mode < Mode.SKIP_MERGE
-            )
+            with trace.span("ingress.close", seq=self._seq) as closing:
+                window = self.builder.close(
+                    self._seq, allow_merge=self.ladder.mode < Mode.SKIP_MERGE
+                )
+                if window is not None:
+                    closing.set(n=window.n_requests, admit_s=self._admit_s)
+                    self._admit_s = 0.0
             if window is None:
                 self._slots.release()
                 self._have_work.clear()
@@ -302,16 +319,19 @@ class ServeFrontend:
             self.telemetry.ingress_queue_depth.set(self.builder.depth)
             if self.builder.depth == 0:
                 self._have_work.clear()
-            self._dispatch_q.put(window)
+            self._dispatch_q.put((window, closing.end))
 
     def _worker_loop(self) -> None:
         """Single consumer of closed windows — runtime.tick is blocking
         and stateful, so it runs here, strictly in seq order."""
         while True:
-            window = self._dispatch_q.get()
-            if window is None:
+            item = self._dispatch_q.get()
+            if item is None:
                 return
+            window, closed_at = item
             self._tick_started = time.perf_counter()
+            trace.record("ingress.queued", closed_at, self._tick_started,
+                         seq=window.seq)
             report: TickReport | None = None
             err: BaseException | None = None
             try:
@@ -347,6 +367,16 @@ class ServeFrontend:
         report: TickReport | None,
         err: BaseException | None,
     ) -> None:
+        with trace.span("ingress.complete", seq=window.seq,
+                        n=window.n_requests):
+            self._resolve_acks(window, report, err)
+
+    def _resolve_acks(
+        self,
+        window: TickWindow,
+        report: TickReport | None,
+        err: BaseException | None,
+    ) -> None:
         tel = self.telemetry
         now = time.perf_counter()
         if report is not None:
@@ -377,7 +407,6 @@ class ServeFrontend:
             assert report is not None
             latency = now - t0
             tel.ingress_acked.inc()
-            tel.ingress_request_seconds.observe(latency)
             fut.set_result(Ack(
                 req.request_id, "ok",
                 tick=report.tick,
@@ -411,6 +440,11 @@ class ServeFrontend:
                 self.builder.depth / self.admission.capacity
                 >= cfg.admission.depth_high_frac
             )
+            for cause, pressured in (
+                ("stall", stalled), ("p99", p99_over), ("depth", depth_high),
+            ):
+                if pressured:
+                    self._pressure[cause].inc()
             before = self.ladder.mode
             after = self.ladder.check(stalled or p99_over or depth_high)
             if after != before:

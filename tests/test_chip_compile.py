@@ -12,6 +12,7 @@ The topology is described inside a module fixture, never at import:
 only one process at a time may load the TPU library.
 """
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -65,8 +66,15 @@ def _shape(sharding, shape, dtype=jnp.float32):
     return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
 
 
-def _native(compiled) -> None:
-    assert "tpu_custom_call" in compiled.as_text()
+def _native(compiled, kernel: str | None = None) -> None:
+    """The program holds a Mosaic kernel; ``kernel`` names the one whose
+    op name a chip trace must show (the benchmark's readers match it)."""
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text
+    if kernel is not None:
+        assert re.search(
+            rf"%{kernel}\.\d+ = [^\n]*custom_call_target=\"tpu_custom_call\"", text
+        ), kernel
 
 
 @pytest.mark.parametrize("window", [2, 32])
@@ -80,7 +88,7 @@ def test_fleet_ingest_compiles(one_chip, width, window):
     )
     _native(fleet_ingest_kernel.lower(
         states, s(d, window, n), interpret=False
-    ).compile())
+    ).compile(), "fleet_ingest_kernel")
 
 
 @pytest.mark.parametrize("width", sorted(WIDTHS))
@@ -89,7 +97,7 @@ def test_banded_merge_solve_compiles(one_chip, width):
     w = _shape(one_chip, (d, nh, nh + n))
     _native(banded_merge_solve.lower(
         w, 2, ridge=1e-3, interpret=False
-    ).compile())
+    ).compile(), "banded_merge_solve")
 
 
 @pytest.mark.parametrize("width", sorted(WIDTHS))
@@ -110,7 +118,7 @@ def test_masked_segment_sum_mix_compiles(one_chip, width):
     ))
     _native(fn.lower(
         _shape(one_chip, (d, nh, nh + n)), _shape(one_chip, (d,))
-    ).compile())
+    ).compile(), "_masked_segment_sum_mix_call")
 
 
 @pytest.mark.parametrize("width", sorted(WIDTHS))

@@ -425,3 +425,51 @@ def test_paged_runtime_telemetry_gauges(fleet, tmp_path):
     assert tiers.get(("intra",), 0) > tiers.get(("inter",), 0) > 0
     summary = paged.finalize_telemetry()
     assert summary["ticks"] == 3
+
+
+# ----------------------------------------------------------- program spans
+
+
+@pytest.mark.parametrize(
+    "topology,mode",
+    [(star(D), "global"), (hierarchical(D, 6, head_exchange=False), "clusters"),
+     (ring(D, hops=2), "ring")],
+    ids=lambda x: getattr(x, "name", x),
+)
+def test_paged_tick_and_merge_spans(fleet, topology, mode):
+    """A paged tick records page.stage/wait/store once per page inside
+    tick.ingest (with tick.detect), and each merge mode records
+    merge.gather/solve/fanout under tick.merge; the report's seconds
+    are those spans' durations."""
+    import json
+    import time
+
+    from repro.obs import spans_between
+
+    cfg = _config(topology)
+    paged = CohortFleetRuntime(_arena(fleet), cfg, cohort_size=C)
+    assert paged.merger.mode == mode
+    t0 = time.perf_counter()
+    reports = [paged.tick(b) for b in _tick_batches(3)]
+    spans = spans_between(t0, time.perf_counter())
+    by_id = {s.id: s for s in spans}
+    parent = lambda s: by_id[s.parent].name  # noqa: E731
+    n_pages = D // C
+    for t, rep in enumerate(reports):
+        mine = [s for s in spans if s.seq == t]
+        ingest = next(s for s in mine if s.name == "tick.ingest")
+        assert rep.ingest_seconds == ingest.seconds
+        for name in ("page.stage", "page.wait", "page.store"):
+            pages = [s for s in mine if s.name == name]
+            assert [s.attrs["page"] for s in pages] == list(range(n_pages)), name
+            assert all(parent(s) == "tick.ingest" for s in pages)
+        assert parent(next(s for s in mine if s.name == "tick.detect")) == "tick.ingest"
+    merge = next(s for s in spans if s.name == "tick.merge")
+    assert reports[merge.seq].merge_seconds == merge.seconds
+    parts = [s for s in spans if s.name.startswith("merge.")]
+    assert {s.name for s in parts} == {"merge.gather", "merge.solve", "merge.fanout"}
+    assert all(parent(s) == "tick.merge" and s.seq == merge.seq for s in parts)
+    fanout = [s for s in parts if s.name == "merge.fanout"]
+    assert len(fanout) == (n_pages if mode == "ring" else 1)
+    assert all(merge.start <= s.start <= s.end <= merge.end for s in parts)
+    json.dumps([s.attrs for s in spans])  # numeric attributes only

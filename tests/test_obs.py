@@ -1,9 +1,13 @@
 """Telemetry subsystem tests: histogram bucket semantics, counter
 monotonicity (including across snapshot/restore), registry exposition
-and state round-trips, span tracing, the flight-recorder ring, and the
-runtime integration — compile-once with the sink on, flight dumps on
-injected NaN payloads, and the bounded detections log."""
+and state round-trips, program spans (the bounded ring, parents across
+threads, the JSONL export, phase histograms fed from spans), the
+flight-recorder ring, and the runtime integration — compile-once with
+the sink on, flight dumps on injected NaN payloads, and the bounded
+detections log."""
 import json
+import threading
+import time
 
 import jax
 import numpy as np
@@ -20,11 +24,14 @@ from repro.obs import (
     FlightRecorder,
     Histogram,
     MetricsRegistry,
+    SpanRing,
     TelemetryConfig,
     TelemetrySink,
     Tracer,
     load_dump,
-    phase_timer,
+    record,
+    span,
+    spans_between,
 )
 from repro.runtime import (
     DetectorConfig,
@@ -167,40 +174,152 @@ def test_registry_load_rejects_bucket_mismatch():
 
 
 def test_phase_timer_fences_device_work():
-    seen = []
-    with phase_timer(seen.append) as handle:
+    """A phase span fenced on its device work observes exactly the
+    span's own duration into the phase histogram."""
+    sink = TelemetrySink(TelemetryConfig())
+    h = sink.phase_seconds.labels(phase="tick.ingest")
+    with sink.phase("tick.ingest") as sp:
         x = jax.numpy.ones((256, 256)) @ jax.numpy.ones((256, 256))
-        handle.fence(x)
-    assert len(seen) == 1 and seen[0] > 0
-    # fencing nothing still observes
-    with phase_timer(seen.append):
+        jax.block_until_ready(x)
+    assert h.count == 1 and h.sum == sp.seconds > 0
+    # an empty phase still observes
+    with sink.phase("tick.ingest"):
         pass
-    assert len(seen) == 2
+    assert h.count == 2
 
 
 # --------------------------------------------------------------------- trace
 
 
 def test_tracer_writes_parseable_jsonl(tmp_path):
+    ring = SpanRing(16)
     path = tmp_path / "trace.jsonl"
-    tr = Tracer(path)
-    with tr.span("merge", tick=3):
+    tr = Tracer(path, ring=ring)
+    with span("merge", seq=3, ring=ring, participants=5):
         pass
-    tr.emit({"name": "flight_dump", "tick": 3})
+    record("ingress.queued", 1.0, 1.5, seq=3, ring=ring)
     tr.close()
     events = [json.loads(line) for line in path.read_text().splitlines()]
-    assert [e["name"] for e in events] == ["merge", "flight_dump"]
-    assert events[0]["tick"] == 3
-    assert events[0]["dur_s"] >= 0
-    assert tr.events_emitted == 2
+    assert [e["name"] for e in events] == ["merge", "ingress.queued"]
+    assert events[0]["seq"] == 3 and events[0]["participants"] == 5
+    assert events[0]["dur_s"] >= 0 and events[0]["parent"] == -1
+    assert events[1]["dur_s"] == 0.5
+    assert tr.events_written == 2
 
 
 def test_tracer_disabled_is_noop(tmp_path):
-    tr = Tracer(None)
-    assert not tr.enabled
-    with tr.span("x"):
+    """Without a path the tracer writes nothing; the spans still record."""
+    ring = SpanRing(16)
+    tr = Tracer(None, ring=ring)
+    with span("x", ring=ring):
         pass
-    assert tr.events_emitted == 0
+    tr.close()
+    assert tr.events_written == 0
+    assert [s.name for s in ring.rows()] == ["x"]
+    assert list(tmp_path.iterdir()) == []
+
+
+def test_tracer_writes_only_at_flush(tmp_path):
+    """Recording never touches the file; each flush appends the spans
+    closed since the previous one."""
+    ring = SpanRing(64)
+    path = tmp_path / "trace.jsonl"
+    tr = Tracer(path, ring=ring)
+    for i in range(5):
+        with span("tick", seq=i, ring=ring):
+            pass
+    assert path.read_text() == ""
+    tr.flush()
+    assert len(path.read_text().splitlines()) == 5
+    with span("tick", seq=5, ring=ring):
+        pass
+    assert len(path.read_text().splitlines()) == 5
+    tr.close()
+    seqs = [json.loads(x)["seq"] for x in path.read_text().splitlines()]
+    assert seqs == [0, 1, 2, 3, 4, 5]
+
+
+def test_span_ring_bounded_overwrites_oldest():
+    """A full ring overwrites its oldest slot: capacity bounds memory,
+    the newest spans are kept, and the columns never grow."""
+    ring = SpanRing(4)
+    cols = ring._start
+    for i in range(11):
+        record("page.stage", float(i), i + 0.5, seq=i, ring=ring)
+    assert ring.recorded == 11
+    assert ring._start is cols and cols.shape == (4,)
+    assert [s.seq for s in ring.rows()] == [7, 8, 9, 10]
+    assert [s.seq for s in ring.between(8.0, 10.5)] == [8, 9, 10]
+    # rows() from a position the ring has overwritten starts at the oldest kept
+    assert [s.seq for s in ring.rows(2)] == [7, 8, 9, 10]
+    with pytest.raises(ValueError):
+        SpanRing(0)
+
+
+def test_span_parents_and_seq_nest_per_thread():
+    """Children name their parent and inherit its seq; a span opened on
+    another thread starts its own tree."""
+    ring = SpanRing(64)
+    seen = {}
+
+    def worker():
+        with span("tick", seq=9, ring=ring) as root:
+            with span("tick.ingest", ring=ring) as child:
+                seen["w"] = (root, child)
+
+    with span("ingress.close", seq=4, ring=ring) as outer:
+        th = threading.Thread(target=worker)
+        th.start()
+        th.join(timeout=30)
+        assert not th.is_alive()
+        with span("ingress.complete", ring=ring) as inner:
+            pass
+    rows = {s.name: s for s in ring.rows()}
+    assert rows["ingress.complete"].parent == rows["ingress.close"].id
+    assert rows["ingress.complete"].seq == 4
+    assert rows["ingress.close"].parent == -1
+    # the worker's tree: its root has no parent (not the event loop's span)
+    assert rows["tick"].parent == -1 and rows["tick"].seq == 9
+    assert rows["tick.ingest"].parent == rows["tick"].id
+    assert rows["tick.ingest"].seq == 9
+    assert outer.start <= inner.start <= inner.end <= outer.end
+
+
+def test_span_record_across_threads_and_perf_counter_clock():
+    """A span whose ends were read on two threads is recorded after the
+    fact, on the perf_counter clock spans use."""
+    ring = SpanRing(16)
+    t_close = time.perf_counter()
+    got = []
+
+    def worker():
+        t_pick = time.perf_counter()
+        record("ingress.queued", t_close, t_pick, seq=2, ring=ring)
+        got.append(t_pick)
+
+    th = threading.Thread(target=worker)
+    th.start()
+    th.join(timeout=30)
+    assert not th.is_alive()
+    lo = time.perf_counter()
+    with span("tick", ring=ring):
+        pass
+    hi = time.perf_counter()
+    queued, tick = ring.rows()
+    assert (queued.name, queued.seq) == ("ingress.queued", 2)
+    assert queued.start == t_close and queued.end == got[0]
+    assert lo <= tick.start <= tick.end <= hi
+    with pytest.raises(ValueError):
+        record("x", 0.0, 1.0, ring=ring, a=1, b=2, c=3, d=4)
+    assert ring.recorded == 2
+
+
+def test_spans_between_filters_to_the_window():
+    ring = SpanRing(16)
+    for name, a, b in [("a", 1.0, 2.0), ("b", 2.5, 3.0), ("c", 2.9, 4.1), ("d", 5.0, 6.0)]:
+        record(name, a, b, ring=ring)
+    assert [s.name for s in spans_between(2.0, 4.5, ring=ring)] == ["b", "c"]
+    assert spans_between(7.0, 8.0, ring=ring) == []
 
 
 # -------------------------------------------------------------------- flight
@@ -313,7 +432,54 @@ def test_runtime_compile_once_with_telemetry(obs_scenario):
     # band histograms sampled every tick here: calibrated devices observed
     assert summary["metrics"]["detector_band_width"]["series"][0]["count"] > 0
     # every phase that ran has latency stats
-    assert {"poison", "ingest", "govern"} <= set(summary["phases"])
+    assert {"tick.poison", "tick.put", "tick.ingest", "tick.govern",
+            "tick.merge"} <= set(summary["phases"])
+
+
+def test_runtime_tick_spans_are_the_report_and_phase_timings(obs_scenario):
+    """Each tick is a root span ``tick`` (seq = tick number) over its
+    phase spans; ``TickReport.ingest_seconds``/``merge_seconds`` are
+    the spans' durations and the phase histogram holds the same
+    numbers (one timing, three readers)."""
+    train3, fs, batch = obs_scenario
+    rt = _mk_runtime(fs, train3.n_features, telemetry=TelemetryConfig())
+    feed = TickFeed(fs, batch)
+    t0 = time.perf_counter()
+    reports = [rt.tick(feed.tick_batch(t)) for t in range(17)]
+    spans = spans_between(t0, time.perf_counter())
+    roots = [s for s in spans if s.name == "tick"]
+    assert [s.seq for s in roots] == list(range(17))
+    by_id = {s.id: s for s in spans}
+    for s in spans:
+        if s.name != "tick":
+            assert by_id[s.parent].name == "tick" and s.seq == by_id[s.parent].seq
+    ingest = {s.seq: s for s in spans if s.name == "tick.ingest"}
+    merge = {s.seq: s for s in spans if s.name == "tick.merge"}
+    assert [r.ingest_seconds for r in reports] == [ingest[t].seconds for t in range(17)]
+    merged = [r.tick for r in reports if r.merge_seconds is not None]
+    assert merged == sorted(merge) == [15]
+    assert reports[15].merge_seconds == merge[15].seconds
+    h = rt.telemetry.phase_seconds.labels(phase="tick.ingest")
+    assert h.count == 17
+    assert h.sum == pytest.approx(sum(s.seconds for s in ingest.values()), rel=1e-12)
+    # a put precedes each ingest, and the telemetry span closes each tick
+    names = [s.name for s in spans if s.seq == 3 and s.name != "tick"]
+    assert names == ["tick.poison", "tick.put", "tick.ingest", "tick.readback",
+                     "tick.govern", "tick.telemetry"]
+
+
+def test_runtime_records_spans_without_a_sink(obs_scenario):
+    """The ring is always on: a runtime with no telemetry still records
+    its tick spans (the fed benchmark cells run without a sink)."""
+    train3, fs, batch = obs_scenario
+    rt = _mk_runtime(fs, train3.n_features)
+    assert rt.telemetry is None
+    t0 = time.perf_counter()
+    rep = rt.tick(TickFeed(fs, batch).tick_batch(0))
+    spans = spans_between(t0, time.perf_counter())
+    assert [s.name for s in spans if s.name.startswith("tick")] == [
+        "tick.poison", "tick.put", "tick.ingest", "tick.readback", "tick.govern", "tick"]
+    assert rep.ingest_seconds == next(s.seconds for s in spans if s.name == "tick.ingest")
 
 
 def test_runtime_telemetry_counters_survive_restore(tmp_path, obs_scenario):
@@ -388,9 +554,11 @@ def test_sink_rejects_unknown_phase():
     sink = TelemetrySink(TelemetryConfig())
     with pytest.raises(ValueError):
         sink.phase("warp")
-    with sink.phase("ingest"):
+    with pytest.raises(ValueError):
+        sink.phase("page_in")  # the paging phases are page.stage/wait/store
+    with sink.phase("tick.ingest"):
         pass
-    assert sink.phase_seconds.labels(phase="ingest").count == 1
+    assert sink.phase_seconds.labels(phase="tick.ingest").count == 1
 
 
 # ------------------------------------------------- ingress metrics (PR 9)
@@ -399,7 +567,8 @@ def test_sink_rejects_unknown_phase():
 def test_sink_ingress_stats_in_summary():
     """The serving front-end books everything through the runtime sink —
     summary() carries an ingress block with admission outcomes, the
-    degraded-ladder position, and submit-to-ack latency."""
+    degraded-ladder position and the watchdog's pressure by cause; no
+    per-request latency histogram rides in it."""
     sink = TelemetrySink(TelemetryConfig(trace=False))
     sink.ingress_accepted.inc(5)
     sink.ingress_acked.inc(4)
@@ -410,8 +579,7 @@ def test_sink_ingress_stats_in_summary():
     sink.ingress_deferred.labels(reason="comm_budget").inc()
     sink.ingress_degraded_mode.set(2)
     sink.ingress_transitions.labels(mode="stale_scores").inc()
-    sink.ingress_request_seconds.observe(0.004)
-    sink.ingress_request_seconds.observe(0.019)
+    sink.ingress_pressure_checks.labels(cause="stall").inc(3)
 
     ing = sink.summary()["ingress"]
     assert ing["accepted"] == 5 and ing["acked"] == 4
@@ -420,9 +588,11 @@ def test_sink_ingress_stats_in_summary():
     assert ing["deferred"] == {"backpressure": 2, "comm_budget": 1}
     assert ing["degraded_mode"] == 2
     assert ing["degraded_transitions"] == {"stale_scores": 1}
-    assert ing["request_latency"]["count"] == 2
-    assert ing["request_latency"]["p99_s"] > 0
-    assert ing["admission_latency"] is None  # nothing observed yet
+    assert ing["pressure_checks"] == {"stall": 3}
+    assert "request_latency" not in ing and "admission_latency" not in ing
+    families = set(sink.registry.summary())
+    assert "ingress_pressure_checks_total" in families
+    assert not families & {"ingress_request_seconds", "ingress_admission_seconds"}
 
 
 def test_sink_ingress_counters_survive_state_roundtrip():

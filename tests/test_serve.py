@@ -5,6 +5,7 @@ hysteresis, and the async ServeFrontend end-to-end — including
 in-process crash-recovery equivalence (snapshot + WAL replay restores
 the exact pre-crash fleet) and the skip-merge governor veto."""
 import asyncio
+import time
 
 import jax
 import numpy as np
@@ -18,7 +19,7 @@ except ImportError:
     HAVE_HYPOTHESIS = False
 
 from repro.fleet import init_fleet, ring
-from repro.obs import TelemetryConfig
+from repro.obs import TelemetryConfig, spans_between
 from repro.runtime import FleetRuntime, GovernorConfig, RuntimeConfig
 from repro.serve import (
     AdmissionConfig,
@@ -349,6 +350,85 @@ def test_frontend_skip_merge_vetoes_governor():
     assert rt.governor.state.merges == 0
     assert rt.governor.state.deferred_degraded > 0
     assert rt.tick_no >= 4  # ticks kept flowing while merges were vetoed
+
+
+def test_frontend_stall_counts_stall_checks_before_the_ladder_moves():
+    """A worker stalled past the deadline shows up as cause=stall
+    pressure checks, at least ``escalate_after`` of them by the time the
+    ladder leaves NORMAL, and as no other cause."""
+    rt = _runtime()
+    fe = _frontend(
+        rt, tick_deadline_s=0.05, watchdog_interval_s=0.005,
+        ladder=LadderConfig(escalate_after=3, recover_after=10**9),
+        pre_tick=lambda w: time.sleep(0.4) if w.seq == 0 else None,
+    )
+    stall = rt.telemetry.ingress_pressure_checks.labels(cause="stall")
+    seen = []
+    check = fe.ladder.check
+
+    def watched(pressured):
+        mode = check(pressured)
+        seen.append((mode, stall.value))
+        return mode
+
+    fe.ladder.check = watched
+
+    async def drive():
+        await fe.start()
+        ack = await fe.submit(_req(device=0, k=1))
+        await fe.stop()
+        return ack
+
+    assert asyncio.run(drive()).ok  # the stalled tick still finished
+    first = next(i for i, (mode, _) in enumerate(seen) if mode != Mode.NORMAL)
+    assert seen[first][1] >= 3
+    ing = rt.telemetry.ingress_stats()
+    assert ing["pressure_checks"]["stall"] >= 3
+    assert ing["pressure_checks"].get("p99", 0) == 0
+    assert ing["pressure_checks"].get("depth", 0) == 0
+    assert ing["degraded_transitions"].get("skip_merge") == 1
+
+
+def test_frontend_records_ingress_spans_per_window():
+    """Each window records ingress.close (its requests and summed
+    admission time), ingress.queued and ingress.complete, with the
+    window's tick number as seq — the number the acks carry — and no
+    span per request."""
+    rt = _runtime()
+    fe = _frontend(rt, close_at_requests=12, max_delay_s=0.05)
+    rng = _rng(5)
+
+    async def drive():
+        await fe.start()
+        acks = await asyncio.gather(*[
+            fe.submit(SampleRequest(
+                device=i % D, x=rng.normal(size=(1, F)).astype(np.float32),
+                client=f"c{i}",
+            )) for i in range(24)
+        ])
+        await fe.stop()
+        return acks
+
+    t0 = time.perf_counter()
+    acks = asyncio.run(drive())
+    spans = spans_between(t0, time.perf_counter())
+    assert all(a.ok for a in acks)
+    ticks = sorted({a.tick for a in acks})
+    closes = [s for s in spans if s.name == "ingress.close" and "n" in s.attrs]
+    assert sorted(s.seq for s in closes) == ticks
+    assert sum(s.attrs["n"] for s in closes) == 24
+    assert all(s.attrs["admit_s"] > 0 for s in closes)
+    for name in ("ingress.queued", "ingress.complete"):
+        assert sorted(s.seq for s in spans if s.name == name) == ticks, name
+    assert sum(s.attrs["n"] for s in spans if s.name == "ingress.complete") == 24
+    assert sorted(s.seq for s in spans if s.name == "tick") == ticks
+    by_seq = {s.seq: s for s in closes}
+    for s in spans:
+        if s.name == "ingress.queued":  # close, then the worker's pickup
+            assert s.start == by_seq[s.seq].end and s.end >= s.start
+    n_ingress = sum(s.name.startswith("ingress.") for s in spans)
+    n_closes = sum(s.name == "ingress.close" for s in spans)
+    assert n_ingress == n_closes + 2 * len(ticks)
 
 
 def test_frontend_requires_telemetry():
