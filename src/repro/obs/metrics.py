@@ -18,7 +18,8 @@ Conventions (Prometheus-flavored, but deliberately tiny):
 - **Histogram** — fixed upper-bound bucket edges (``le`` semantics,
   +Inf implicit) plus a bounded window of raw samples so quantiles
   (``quantile(0.99)``) are exact over the retained window instead of
-  bucket-interpolated.
+  bucket-interpolated. A quantile is computed once per change of the
+  window: reads between observes return the kept value.
 - **Labels** — a metric declared with ``labels=("phase",)`` is a family;
   ``family.labels(phase="merge")`` lazily materializes one child per
   label value. Children are ordinary metrics.
@@ -84,11 +85,14 @@ class Histogram:
     implicit +Inf bucket catches the tail. ``quantile`` is computed
     over the retained raw samples (the most recent ``sample_cap``
     observations) — exact for runs shorter than the cap, a sliding
-    window beyond it.
+    window beyond it. Each q's value is kept until the samples change
+    (``observe``, ``observe_many``, ``load``), so a caller may read it
+    per request; ``evals`` counts the percentiles actually computed.
     """
 
     __slots__ = (
         "buckets", "_edges", "counts", "count", "sum", "vmin", "vmax", "samples",
+        "_quantiles", "evals",
     )
 
     def __init__(
@@ -108,6 +112,12 @@ class Histogram:
         self.vmin = math.inf
         self.vmax = -math.inf
         self.samples: deque[float] = deque(maxlen=sample_cap)
+        # q -> value over the current samples. A change of the samples
+        # rebinds it after the change, never clears it in place: a
+        # quantile computed on another thread across an observe then
+        # lands in the discarded dict instead of outliving the change.
+        self._quantiles: dict[float, float] = {}
+        self.evals = 0
 
     def observe(self, v: float) -> None:
         v = float(v)
@@ -122,6 +132,7 @@ class Histogram:
         if v > self.vmax:
             self.vmax = v
         self.samples.append(v)
+        self._quantiles = {}
 
     def observe_many(self, values: np.ndarray) -> None:
         values = np.asarray(values, np.float64).ravel()
@@ -136,12 +147,20 @@ class Histogram:
         self.vmin = min(self.vmin, float(values.min()))
         self.vmax = max(self.vmax, float(values.max()))
         self.samples.extend(values.tolist())
+        self._quantiles = {}
 
     def quantile(self, q: float) -> float | None:
         """q-quantile over the retained sample window; None when empty."""
         if not self.samples:
             return None
-        return float(np.percentile(np.fromiter(self.samples, np.float64), 100 * q))
+        kept = self._quantiles
+        value = kept.get(q)
+        if value is None:
+            value = kept[q] = float(
+                np.percentile(np.fromiter(self.samples, np.float64), 100 * q)
+            )
+            self.evals += 1
+        return value
 
     def snapshot(self) -> dict:
         return {
@@ -167,6 +186,7 @@ class Histogram:
         self.vmax = -math.inf if state["max"] is None else float(state["max"])
         self.samples.clear()
         self.samples.extend(float(s) for s in state["samples"])
+        self._quantiles = {}
 
 
 class _Family:

@@ -23,8 +23,11 @@ ingress catalog pre-declared in ``repro.obs.sink``): one registry, one
 snapshot-riding state blob, no forked accounting. Host time is recorded
 per window, never per request, as program spans (``repro.obs.trace``)
 whose ``seq`` is the window's tick number: ``ingress.close`` (cutting
-the window; attributes ``n`` requests and ``admit_s``, the admission
-time summed over the submits since the previous window),
+the window; attributes ``n`` requests, ``admit_s``, the admission
+time summed over the submits since the previous window, and
+``p99_evals``, the tick-latency p99s those submits computed: admission
+reads the p99 only under an SLO, and the histogram keeps it between
+ticks, so at most one a tick and none without an SLO),
 ``ingress.queued`` (close to worker pickup) and ``ingress.complete``
 (resolving the window's acks; attribute ``n``).
 """
@@ -143,6 +146,7 @@ class ServeFrontend:
         self._last_drifted = np.zeros(d, bool)
         self._inflight_windows = 0
         self._admit_s = 0.0  # admission seconds since the last window
+        self._p99_evals = 0  # tick p99s computed by submits since then
         self._tick_started: float | None = None
         self._failed: str | None = None
         self._running = False
@@ -228,14 +232,19 @@ class ServeFrontend:
                        f"(D={self.builder.n_devices}, B={self.builder.batch}, "
                        f"F={self.builder.n_features})",
             )
-        t99 = self.telemetry.tick_seconds
+        tick_p99 = None
+        if self.config.admission.slo_p99_s is not None:
+            t99 = tel.tick_seconds
+            evals = t99.evals
+            tick_p99 = t99.quantile(0.99)
+            self._p99_evals += t99.evals - evals
         verdict, reason = self.admission.decide(
             req,
             mode=self.ladder.mode,
             device_depth=self.builder.device_depth(req.device),
             client_inflight=self._client_inflight.get(req.client, 0),
             total_depth=self.builder.depth,
-            tick_p99_s=t99.quantile(0.99) if t99.count else None,
+            tick_p99_s=tick_p99,
             budget_utilization=self.runtime.governor.budget_utilization(),
         )
         self._admit_s += time.perf_counter() - t0
@@ -306,8 +315,10 @@ class ServeFrontend:
                     self._seq, allow_merge=self.ladder.mode < Mode.SKIP_MERGE
                 )
                 if window is not None:
-                    closing.set(n=window.n_requests, admit_s=self._admit_s)
+                    closing.set(n=window.n_requests, admit_s=self._admit_s,
+                                p99_evals=self._p99_evals)
                     self._admit_s = 0.0
+                    self._p99_evals = 0
             if window is None:
                 self._slots.release()
                 self._have_work.clear()

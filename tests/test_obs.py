@@ -6,6 +6,7 @@ flight-recorder ring, and the runtime integration — compile-once with
 the sink on, flight dumps on injected NaN payloads, and the bounded
 detections log."""
 import json
+import sys
 import threading
 import time
 
@@ -98,6 +99,85 @@ def test_histogram_sample_window_is_bounded():
     assert len(h.samples) == 8
     assert list(h.samples) == list(range(92, 100))  # most recent retained
     assert h.count == 100  # aggregate stats still see everything
+
+
+def test_histogram_quantile_kept_until_samples_change(monkeypatch):
+    """quantile() equals np.percentile over the retained samples after
+    observe, observe_many, eviction past sample_cap and load, and reads
+    between changes compute no percentile."""
+    calls = []
+    percentile = np.percentile
+
+    def counted(a, q, *args, **kw):
+        calls.append(q)
+        return percentile(a, q, *args, **kw)
+
+    monkeypatch.setattr(np, "percentile", counted)
+
+    def exact(h, q):
+        return float(percentile(np.asarray(h.samples, np.float64), 100 * q))
+
+    rng = np.random.default_rng(4)
+    h = Histogram(buckets=(0.5, 1.0, 2.0), sample_cap=16)
+    assert h.quantile(0.99) is None and not calls
+    steps = [
+        lambda: h.observe(rng.gamma(1.0)),
+        lambda: h.observe_many(rng.gamma(1.0, size=7)),
+        lambda: h.observe_many(rng.gamma(1.0, size=30)),  # evicts past the cap
+        lambda: [h.observe(v) for v in rng.gamma(1.0, size=3)],  # evicts
+    ]
+    for change in steps:
+        change()
+        before = len(calls)
+        for _ in range(5):
+            assert h.quantile(0.99) == exact(h, 0.99)
+            assert h.quantile(0.5) == exact(h, 0.5)
+        assert len(calls) - before == 2  # once per q until the next change
+    assert len(h.samples) == 16
+    assert h.evals == len(calls) == 8
+
+    other = Histogram(buckets=(0.5, 1.0, 2.0), sample_cap=16)
+    other.observe_many([9.0, 10.0])
+    assert other.quantile(0.99) == exact(other, 0.99)
+    other.load(h.snapshot())
+    assert list(other.samples) == list(h.samples)
+    assert other.quantile(0.99) == exact(other, 0.99) == h.quantile(0.99)
+    assert other.evals == 2
+
+
+def test_histogram_quantile_never_outlives_an_observe_on_another_thread():
+    """Readers on other threads compute quantiles across observes (the
+    served path reads on the event loop while the worker observes); no
+    value computed over the samples before an observe survives it."""
+    qs = [i / 64 for i in range(65)]
+    h = Histogram(buckets=(1.0,), sample_cap=16)
+    switch = sys.getswitchinterval()
+    sys.setswitchinterval(1e-6)
+    try:
+        for round_ in range(20):
+            stop = threading.Event()
+
+            def read():
+                i = 0
+                while not stop.is_set():
+                    h.quantile(qs[i % len(qs)])
+                    i += 1
+
+            readers = [threading.Thread(target=read) for _ in range(8)]
+            for t in readers:
+                t.start()
+            for v in range(50):
+                h.observe(float(round_ * 50 + v))
+                time.sleep(0)
+            stop.set()
+            for t in readers:
+                t.join(timeout=10)
+            assert not any(t.is_alive() for t in readers)
+            window = np.asarray(h.samples, np.float64)
+            for q in qs:
+                assert h.quantile(q) == float(np.percentile(window, 100 * q)), q
+    finally:
+        sys.setswitchinterval(switch)
 
 
 def test_registry_labels_and_redeclare():

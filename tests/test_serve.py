@@ -431,6 +431,126 @@ def test_frontend_records_ingress_spans_per_window():
     assert n_ingress == n_closes + 2 * len(ticks)
 
 
+def _closes_with_ticks(fe):
+    """Wrap the window cut to note the ticks the tick histogram had
+    observed at each close, in close order."""
+    seen = []
+    close = fe.builder.close
+
+    def watched(*args, **kw):
+        seen.append(fe.telemetry.tick_seconds.count)
+        return close(*args, **kw)
+
+    fe.builder.close = watched
+    return seen
+
+
+def test_frontend_slo_defers_under_load_and_counts_p99_evals():
+    """With an SLO set and every tick over it, submits past the SLO's
+    minimum load answer busy/slo end to end; each window's p99_evals is
+    the p99s its submits computed, at most one per tick observed."""
+    rt = _runtime()
+    fe = _frontend(
+        rt, close_at_requests=64, max_delay_s=0.01,
+        admission=AdmissionConfig(slo_p99_s=1e-9),
+        # keep the watchdog's own p99 reads and the ladder out of it
+        watchdog_interval_s=10.0,
+        ladder=LadderConfig(escalate_after=10**9),
+    )
+    observed = _closes_with_ticks(fe)
+    rng = _rng(11)
+
+    def wave(n):
+        return asyncio.gather(*[
+            fe.submit(SampleRequest(
+                device=i % D, x=rng.normal(size=(1, F)).astype(np.float32),
+                client=f"c{i}",
+            )) for i in range(n)
+        ])
+
+    async def drive():
+        await fe.start()
+        first = await wave(4)   # no tick observed yet: no p99 to read
+        loaded = await wave(40)
+        await fe.stop()
+        return first, loaded
+
+    t0 = time.perf_counter()
+    first, loaded = asyncio.run(drive())
+    spans = spans_between(t0, time.perf_counter())
+    assert all(a.ok for a in first)
+    # capacity 8 x 8 = 64; slo_min_depth_frac 0.25: 16 admitted, then slo
+    statuses = [(a.status, a.reason) for a in loaded]
+    assert statuses.count(("busy", "slo")) == 24
+    assert sum(a.ok for a in loaded) == 16
+    assert rt.telemetry.ingress_stats()["deferred"] == {"slo": 24}
+    closes = [s for s in spans if s.name == "ingress.close" and "n" in s.attrs]
+    evals = [int(s.attrs["p99_evals"]) for s in sorted(closes, key=lambda s: s.seq)]
+    assert evals == [0, 1]  # 44 submits, one tick between them: one p99
+    assert len(observed) == len(evals)
+    for k, n in enumerate(evals):
+        since = observed[k] - (observed[k - 1] if k else 0)
+        assert n <= since
+    assert rt.telemetry.tick_seconds.evals == 1
+
+
+def test_frontend_without_slo_reads_no_p99_and_decides_the_same():
+    """A served run with no SLO computes no tick p99 on the submit path
+    (p99_evals 0 on every window), and its decisions and acks are those
+    of the same run with a never-breached SLO, which reads the p99 on
+    every submit."""
+
+    def run(admission):
+        rt = _runtime(merge_every=2)
+        fe = _frontend(rt, close_at_requests=D, max_delay_s=1.0,
+                       admission=admission,
+                       watchdog_interval_s=10.0)  # no p99 reads of its own
+        decisions = []
+        decide = fe.admission.decide
+
+        def watched(req, **kw):
+            verdict = decide(req, **kw)
+            decisions.append((verdict, kw["tick_p99_s"] is not None))
+            return verdict
+
+        fe.admission.decide = watched
+        rng = _rng(13)
+
+        async def drive():
+            await fe.start()
+            acks = []
+            for _ in range(6):  # whole windows, one request per device
+                acks += await asyncio.gather(*[
+                    fe.submit(SampleRequest(
+                        device=d, x=rng.normal(size=(1, F)).astype(np.float32),
+                        client=f"c{d}",
+                    )) for d in range(D)
+                ])
+            await fe.stop()
+            return acks
+
+        t0 = time.perf_counter()
+        acks = asyncio.run(drive())
+        spans = spans_between(t0, time.perf_counter())
+        evals = [s.attrs["p99_evals"] for s in spans
+                 if s.name == "ingress.close" and "n" in s.attrs]
+        return acks, decisions, evals
+
+    acks, decisions, evals = run(AdmissionConfig())
+    acks_read, decisions_read, evals_read = run(
+        AdmissionConfig(slo_p99_s=float("inf"))
+    )
+    assert len(evals) == 6 and evals == [0] * 6
+    assert not any(read for _, read in decisions)
+    assert sum(read for _, read in decisions_read) == 5 * D  # after tick 0
+    assert evals_read == [0] + [1] * 5  # one p99 a tick observed
+    assert [v for v, _ in decisions] == [v for v, _ in decisions_read]
+    assert all(a.ok for a in acks)
+    assert [(a.status, a.tick, a.score, a.drifted) for a in acks] == [
+        (a.status, a.tick, a.score, a.drifted) for a in acks_read
+    ]
+
+
 def test_frontend_requires_telemetry():
     rng = _rng(0)
     x_init = rng.normal(size=(D, 2 * H, F)).astype(np.float32)
