@@ -208,10 +208,11 @@ class _Driver:
     """Shared pieces: widths, keys and pools from the seed, the tick log,
     the reference's own boot and replay of the checked ticks."""
 
-    def __init__(self, cell: Cell, seed: int):
+    def __init__(self, cell: Cell, seed: int, devices):
         import jax
 
         self.cell = cell
+        self.devices = list(devices)      # the cell's chips
         self.cfg = cell.config
         self.tr = cell.traffic
         self.seed = int(seed)
@@ -243,29 +244,52 @@ class _Driver:
         )
 
     def _boot_blocks(self):
-        return [(0, self.n_dev)]
+        """The contiguous device ranges [lo, hi) the fleet is booted in:
+        one per cohort of a paged fleet, else one per chip of the cell."""
+        if self.cfg["runtime"] == "paged":
+            c = int(self.cfg["cohort_size"])
+            return [(lo, lo + c) for lo in range(0, self.n_dev, c)]
+        chips = len(self.devices)
+        if self.n_dev % chips:
+            raise ValueError(f"config {self.cfg['name']!r}: n_devices {self.n_dev} "
+                             f"does not divide over {chips} chips")
+        per = self.n_dev // chips
+        return [(lo, lo + per) for lo in range(0, self.n_dev, per)]
+
+    def _block_devices(self):
+        """The chip each boot block lives on: the blocks in order, spread
+        evenly over the cell's chips."""
+        n = len(self._boot_blocks())
+        return [self.devices[i * len(self.devices) // n] for i in range(n)]
 
     def _resident_fleet(self):
-        """The fleet state on the device, from the seed, in one jitted call;
-        also each device's last boot row (the front-end's fallback). The
-        boot is set-up, run at float32 as the configuration states (see
+        """The fleet state from the seed, block (lo, hi) of
+        ``_boot_blocks`` booted on its chip in one jitted call from
+        ``_boot_x(lo, hi)``, and laid out by device range over the cell's
+        chips (``lay_out``); also each block's last boot rows on its chip
+        (the front-end's fallback rows, block by block). The boot is
+        set-up, run at float32 as the configuration states (see
         ``float32_boot``)."""
         import jax
 
         from repro.fleet import init_fleet
 
-        d, f, h = self.n_dev, self.n_feat, self.n_hid
-
-        @jax.jit
-        def build(kb, kx):
+        def build(kb, kx, *, lo, hi):
             # keys are arguments, not constants: one program for every seed
-            x0 = self._boot_x(0, d, kx)
-            fleet = init_fleet(kb, d, f, h, x0, activation=self.act,
-                               ridge=self.ridge, forget=float(self.cfg["forget"]))
+            x0 = self._boot_x(lo, hi, kx)
+            fleet = init_fleet(kb, hi - lo, self.n_feat, self.n_hid, x0,
+                               activation=self.act, ridge=self.ridge,
+                               forget=float(self.cfg["forget"]))
             return fleet, x0[:, -1, :]
 
-        with float32_boot():
-            return build(self.key_basis, self.key_boot)
+        build = jax.jit(build, static_argnames=("lo", "hi"))
+        blocks, rows = [], []
+        for (lo, hi), dev in zip(self._boot_blocks(), self._block_devices()):
+            with float32_boot(), jax.default_device(dev):
+                fleet, last = build(self.key_basis, self.key_boot, lo=lo, hi=hi)
+            blocks.append(fleet)
+            rows.append(last)
+        return lay_out(blocks, self.devices), rows
 
     def built(self):
         """Called once the runtime exists, before its first tick."""
@@ -297,15 +321,19 @@ class _Driver:
     def replay(self, precision: str) -> dict:
         """The reference's own boot of the same fleet from the same seed,
         then every checked tick: its per-tick losses and final state."""
+        import jax
+
         from bench import reference as ref
 
         alpha, bias = ref.basis(self.key_basis, self.n_feat, self.n_hid)
-        blocks = [
-            ref.boot(alpha, bias, self._boot_x(lo, hi), activation=self.act,
-                     ridge=self.ridge, precision=precision)
-            for lo, hi in self._boot_blocks()
-        ]
-        fleet = ref.Fleet(blocks, activation=self.act, ridge=self.ridge,
+        devices = self._block_devices()
+        blocks = []
+        for (lo, hi), dev in zip(self._boot_blocks(), devices):
+            with jax.default_device(dev):
+                blocks.append(ref.boot(alpha, bias, self._boot_x(lo, hi),
+                                       activation=self.act, ridge=self.ridge,
+                                       precision=precision))
+        fleet = ref.Fleet(blocks, devices, activation=self.act, ridge=self.ridge,
                           precision=precision)
         losses = []
         for rec in self.log.ticks[:self.log.checked]:
@@ -334,7 +362,7 @@ class Served(_Driver):
         from repro.serve import ServeConfig, ServeFrontend
 
         tr = self.tr
-        fleet, fallback = self._resident_fleet()
+        fleet, rows = self._resident_fleet()
         self.runtime = FleetRuntime(fleet, RuntimeConfig(
             topology=self._topology(), ridge=self.ridge,
             governor=GovernorConfig(merge_every=int(tr["merge_every"])),
@@ -346,8 +374,8 @@ class Served(_Driver):
             max_delay_s=float(tr["max_delay_ms"]) / 1e3,
             close_at_requests=int(tr["close_at_requests"]),
             seed=self.seed,
-        ), fallback=np.asarray(fallback))
-        del fleet, fallback
+        ), fallback=np.concatenate([np.asarray(r) for r in rows]))
+        del fleet, rows
         self.pool = self._pool(
             (int(tr["pool_per_device"]), self.n_dev, self.n_feat), 1)
         self.next_sample = np.zeros(self.n_dev, np.int64)
@@ -597,12 +625,6 @@ class Feed(_Driver):
             self._warm_rebase()
         self.end_check_phase()
 
-    def _boot_blocks(self):
-        if self.cfg["runtime"] == "paged":
-            c = int(self.cfg["cohort_size"])
-            return [(lo, lo + c) for lo in range(0, self.n_dev, c)]
-        return [(0, self.n_dev)]
-
     def _warm_rebase(self):
         """The paged runtime's post-merge rebase program first runs on the
         tick after a merge, inside the window: compile it now on the
@@ -684,6 +706,31 @@ class Feed(_Driver):
 DRIVERS = {"served_closed": Served, "feed": Feed}
 
 
+def lay_out(blocks: list, devices: list):
+    """One fleet from per-chip blocks of consecutive device ranges, block
+    i on ``devices[i]``. Every leaf is stacked by device (the program
+    keeps the shared basis once per device too) and is laid out by device
+    range over a 1-D mesh of the chips; the basis must be the same in
+    every block. One chip: its block as it is."""
+    if len(blocks) == 1:
+        return blocks[0]
+    import jax
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec
+
+    basis = [np.asarray(leaf[0]) for leaf in jax.tree.leaves(blocks[0].params)]
+    for i, block in enumerate(blocks[1:], 1):
+        if not all(np.array_equal(np.asarray(leaf[0]), want)
+                   for leaf, want in zip(jax.tree.leaves(block.params), basis)):
+            raise ValueError(f"boot block {i} has another basis than block 0")
+    by_range = NamedSharding(Mesh(np.array(devices), ("fleet",)), PartitionSpec("fleet"))
+
+    def one(*leaves):
+        shape = (sum(leaf.shape[0] for leaf in leaves),) + leaves[0].shape[1:]
+        return jax.make_array_from_single_device_arrays(shape, by_range, list(leaves))
+
+    return jax.tree.map(one, *blocks)
+
+
 # ------------------------------------------------------------------- run
 
 
@@ -712,20 +759,22 @@ def scaled(cell: Cell, scale: dict | None) -> Cell:
 
 
 def run_cell(cell: Cell, *, seed: int, seconds: float, trace: bool,
-             t_start: float, peaks: dict | None, device, chips: int = 1,
+             t_start: float, peaks: dict | None, devices,
              control: bool = False, driver=None) -> Outcome:
-    """One run of ``cell``. ``control`` puts the reference at bfloat16
-    operands in the program's place for the check, which must then read
-    ``correct`` false; ``driver`` replaces the driver class (fault
-    rehearsals)."""
+    """One run of ``cell`` on ``devices``, the cell's chips. ``control``
+    puts the reference at bfloat16 operands in the program's place for
+    the check, which must then read ``correct`` false; ``driver``
+    replaces the driver class (fault rehearsals)."""
     import gc
     import shutil
     import tempfile
 
     import jax
 
+    if len(devices) != cell.chips:
+        raise ValueError(f"{cell.name} runs on {cell.chips} chips, given {len(devices)}")
     compiles = CompileCounter()
-    drv = (driver or DRIVERS[cell.traffic["kind"]])(cell, seed)
+    drv = (driver or DRIVERS[cell.traffic["kind"]])(cell, seed, devices)
     drv.setup()
     notes = []
     tdir = tempfile.mkdtemp(prefix="bench_trace_") if trace else None
@@ -751,10 +800,11 @@ def run_cell(cell: Cell, *, seed: int, seconds: float, trace: bool,
     drv.run(seconds, on_start, on_end)
     if tdir:
         jax.profiler.stop_trace()
-    stats = device.memory_stats() or {}
+    peak = [int((d.memory_stats() or {}).get("peak_bytes_in_use", 0)) for d in devices]
+    notes.append(f"memory_peak_bytes by chip: {peak}")
     dev_info = {
-        "platform": device.platform, "kind": device.device_kind, "count": chips,
-        "memory_peak_bytes": int(stats.get("peak_bytes_in_use", 0)),
+        "platform": devices[0].platform, "kind": devices[0].device_kind,
+        "count": len(devices), "memory_peak_bytes": max(peak),
     }
     attempted, failed = drv.attempted_failed()
     ctx = Context(cell=cell, setup_s=marks["setup_s"], log=drv.log, peaks=peaks)
@@ -762,7 +812,7 @@ def run_cell(cell: Cell, *, seed: int, seconds: float, trace: bool,
     if tdir:
         from bench import trace as trace_mod
 
-        ctx.trace = trace_mod.reduce_dir(tdir, drv.log.window)
+        ctx.trace = trace_mod.reduce_dir(tdir, drv.log.window, [d.id for d in devices])
         shutil.rmtree(tdir, ignore_errors=True)
         if ctx.trace is not None and ctx.trace.device_events:
             dev_info["busy_s"] = ctx.trace.busy_s

@@ -1,11 +1,12 @@
 """ingest_kernel_roofline: the least time the window's ingest work could
-take on this chip over the summed device time of the ingest kernel's
-events in the trace, in %.
+take on one chip over the device time of the ingest kernel's events in
+the trace, summed over the cell's chips, in %.
 
 The work is what the served rows need, from unpadded widths (costs.py):
 each served device's k=1 chain over its window, its state read and
 written once and its window read once, plus the shared basis once per
-kernel call. The least time is the larger of operations over the bf16
+kernel call: one call a tick on each chip of a resident fleet, one per
+cohort of a paged one. The least time is the larger of operations over the bf16
 peak and bytes over HBM bandwidth; at every cell so far the bytes set it.
 """
 from bench import costs
@@ -22,7 +23,10 @@ def read(ctx):
     cfg, tr = ctx.cell.config, ctx.cell.traffic
     n, h, act = cfg["n_features"], cfg["n_hidden"], cfg["activation"]
     steps = costs.steps_per_row(tr)
-    calls = cfg["n_devices"] // cfg.get("cohort_size", cfg["n_devices"])
+    if "cohort_size" in cfg:
+        calls = cfg["n_devices"] // cfg["cohort_size"]
+    else:
+        calls = ctx.cell.chips
     least, bounds = 0.0, set()
     for r in ctx.log.in_window():
         flops = r.served_rows * steps * costs.sample_flops(n, h, act)

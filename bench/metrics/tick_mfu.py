@@ -1,7 +1,7 @@
 """tick_mfu: model operations of the window's work over the window's
-seconds times the chip's bf16 peak, in %: the k=1 chain of every served
-row's samples plus Eq. 8 for each merge round, from unpadded widths
-(costs.py). The chain is float32 vector work, so the bf16 peak is more
+seconds times the bf16 peak of the cell's chips (chips x one chip's),
+in %: the k=1 chain of every served row's samples plus Eq. 8 for each
+merge round, from unpadded widths (costs.py). The chain is float32 vector work, so the bf16 peak is more
 than it can reach; the share is an upper bound on the room left."""
 from bench import costs
 
@@ -22,4 +22,5 @@ def read(ctx):
             flops += costs.merge_flops(n, h, cfg["n_devices"], int(r.mask.sum()),
                                        cfg["topology"], cfg.get("hops", 0))
     w0, w1 = ctx.log.window
-    return flops / ((w1 - w0) * ctx.peaks["bf16_flops_per_s"]) * 100.0
+    peak = ctx.cell.chips * ctx.peaks["bf16_flops_per_s"]
+    return flops / ((w1 - w0) * peak) * 100.0

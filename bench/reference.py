@@ -146,6 +146,34 @@ def band_sum(x, *, hops: int):
     return sum(jnp.roll(x, o, axis=0) for o in range(-hops, hops + 1))
 
 
+@partial(jax.jit, static_argnames=("hops",))
+def band_sum_halo(prev, x, nxt, *, hops: int):
+    """``band_sum`` of one block of a ring split into blocks: ``prev`` is
+    the last ``hops`` rows of the block before it, ``nxt`` the first
+    ``hops`` rows of the block after it. The terms are added in
+    ``band_sum``'s order."""
+    ext = jnp.concatenate([prev, x, nxt])
+    n = x.shape[0]
+    return sum(ext[hops - o:hops - o + n] for o in range(-hops, hops + 1))
+
+
+def band_sums(xs, hops: int, devices):
+    """``band_sum`` over the concatenation of the blocks ``xs``, computed
+    block by block on ``devices[i]``: each block takes ``hops`` rows from
+    each neighbouring block, and the last block's neighbour is block 0."""
+    if min(int(x.shape[0]) for x in xs) < hops:
+        raise ValueError(f"a ring block holds fewer than hops={hops} devices "
+                         f"(blocks of {[int(x.shape[0]) for x in xs]})")
+    if len(xs) == 1:
+        return [band_sum(xs[0], hops=hops)]
+    out = []
+    for i, (x, dev) in enumerate(zip(xs, devices)):
+        before, after = xs[i - 1], xs[(i + 1) % len(xs)]
+        halo = jax.device_put((before[before.shape[0] - hops:], after[:hops]), dev)
+        out.append(band_sum_halo(halo[0], x, halo[1], hops=hops))
+    return out
+
+
 @jax.jit
 def keep_where(mask, new_p, new_b, p, beta):
     sel = mask.astype(bool)[:, None, None]
@@ -153,13 +181,18 @@ def keep_where(mask, new_p, new_b, p, beta):
 
 
 class Fleet:
-    """Reference fleet state held on the device in blocks of devices."""
+    """Reference fleet state held on the device in blocks of devices:
+    block i (a contiguous device range) lives on ``devices[i]``, and its
+    work runs there."""
 
-    def __init__(self, blocks, *, activation: str, ridge: float,
+    def __init__(self, blocks, devices, *, activation: str, ridge: float,
                  precision: str = "highest"):
         if precision not in PRECISIONS:
             raise ValueError(f"precision {precision!r} not in {PRECISIONS}")
-        self.blocks = blocks  # list of (p, beta), contiguous device ranges
+        if len(devices) != len(blocks):
+            raise ValueError(f"{len(blocks)} blocks on {len(devices)} devices")
+        self.devices = list(devices)
+        self.blocks = [jax.device_put(b, d) for b, d in zip(blocks, self.devices)]
         self.activation = activation
         self.ridge = ridge
         self.precision = precision
@@ -168,55 +201,57 @@ class Fleet:
     def n_devices(self) -> int:
         return sum(int(p.shape[0]) for p, _ in self.blocks)
 
+    def _bounds(self):
+        bounds, lo = [], 0
+        for p, _ in self.blocks:
+            bounds.append((lo, lo + int(p.shape[0])))
+            lo = bounds[-1][1]
+        return bounds
+
     def tick(self, alpha, bias, window_fn, served):
         """window_fn(lo, hi) -> (hi-lo, T, F) host array; served (D,) bool.
         Returns the (D,) losses as a host array."""
         import numpy as np
 
         losses = np.empty(self.n_devices, np.float32)
-        lo = 0
-        for i, (p, b) in enumerate(self.blocks):
-            hi = lo + int(p.shape[0])
-            p, b, lj = ingest(
-                p, b, alpha, bias, jnp.asarray(window_fn(lo, hi)),
-                jnp.asarray(served[lo:hi]), activation=self.activation,
-                precision=self.precision,
-            )
+        for i, (lo, hi) in enumerate(self._bounds()):
+            p, b = self.blocks[i]
+            window, srv = jax.device_put((window_fn(lo, hi), served[lo:hi]),
+                                         self.devices[i])
+            p, b, lj = ingest(p, b, alpha, bias, window, srv,
+                              activation=self.activation, precision=self.precision)
             self.blocks[i] = (p, b)
             losses[lo:hi] = np.asarray(lj)
-            lo = hi
         return losses
 
     def merge(self, mask, topology: str, hops: int = 0):
         """One masked Eq. 8 round; topology "star" or "ring"."""
-        bounds, lo = [], 0
-        for p, _ in self.blocks:
-            bounds.append((lo, lo + int(p.shape[0])))
-            lo = bounds[-1][1]
+        bounds = self._bounds()
+        masks = [jax.device_put(mask[lo:hi], d) for (lo, hi), d in zip(bounds, self.devices)]
         parts = [
-            payload(p, b, jnp.asarray(mask[lo:hi]), ridge=self.ridge,
-                    precision=self.precision)
-            for (p, b), (lo, hi) in zip(self.blocks, bounds)
+            payload(p, b, m, ridge=self.ridge, precision=self.precision)
+            for (p, b), m in zip(self.blocks, masks)
         ]
         if topology == "star":
-            su = sum(u.sum(0) for u, _ in parts)
-            sv = sum(v.sum(0) for _, v in parts)
-            pm, bm = solve(su[None], sv[None], ridge=self.ridge)
+            home = self.devices[0]
+            su = sum(jax.device_put(u.sum(0), home) for u, _ in parts)
+            sv = sum(jax.device_put(v.sum(0), home) for _, v in parts)
+            merged = solve(su[None], sv[None], ridge=self.ridge)
             for i, ((p, b), (lo, hi)) in enumerate(zip(self.blocks, bounds)):
                 d = hi - lo
+                pm, bm = jax.device_put(merged, self.devices[i])
                 self.blocks[i] = keep_where(
-                    jnp.asarray(mask[lo:hi]),
+                    masks[i],
                     jnp.broadcast_to(pm, (d,) + pm.shape[1:]),
                     jnp.broadcast_to(bm, (d,) + bm.shape[1:]), p, b,
                 )
         elif topology == "ring":
-            if len(self.blocks) != 1:
-                raise ValueError("the reference ring merge holds one block")
-            u, v = parts[0]
-            pm, bm = solve(band_sum(u, hops=hops), band_sum(v, hops=hops),
-                           ridge=self.ridge)
-            p, b = self.blocks[0]
-            self.blocks[0] = keep_where(jnp.asarray(mask), pm, bm, p, b)
+            su = band_sums([u for u, _ in parts], hops, self.devices)
+            sv = band_sums([v for _, v in parts], hops, self.devices)
+            del parts
+            for i, (p, b) in enumerate(self.blocks):
+                pm, bm = solve(su[i], sv[i], ridge=self.ridge)
+                self.blocks[i] = keep_where(masks[i], pm, bm, p, b)
         else:
             raise ValueError(f"unknown topology {topology!r}")
 

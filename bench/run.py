@@ -72,7 +72,7 @@ def main(argv=None) -> int:
 
     out = harness.run_cell(
         cell, seed=args.seed, seconds=args.seconds, trace=bool(args.trace),
-        t_start=T_START, peaks=peaks, device=devices[0], chips=cell.chips,
+        t_start=T_START, peaks=peaks, devices=devices[:cell.chips],
         control=bool(args.control),
     )
     for note in out.notes:

@@ -34,7 +34,7 @@ def _run(name, seed, driver=None, trace=False, control=False):
     seconds = 8.0 if cell.traffic["kind"].startswith("served") else 0.5
     return harness.run_cell(
         cell, seed=seed, seconds=seconds, trace=trace,
-        t_start=time.perf_counter(), peaks=None, device=jax.devices()[0],
+        t_start=time.perf_counter(), peaks=None, devices=jax.devices()[:1],
         control=control, driver=driver,
     )
 

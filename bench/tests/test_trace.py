@@ -60,7 +60,7 @@ def _profile(planes):
 
 def test_recorded_v5e_trace():
     rec = json.loads((DATA / "trace_small.json").read_text())
-    red = trace.reduce_profile(_profile(rec["planes"]), tuple(rec["host_window"]))
+    red = trace.reduce_profile(_profile(rec["planes"]), tuple(rec["host_window"]), [0])
     want = rec["expect"]
     assert red.busy_s == pytest.approx(want["busy_s"])
     assert red.window_s == pytest.approx(want["window_s"])
@@ -82,5 +82,38 @@ def test_only_chip_planes_count():
     chip = {"name": "/device:TPU:0", "lines": [
         {"name": "XLA Ops", "events": [["%a.1 = f32[2] add(...)", 10, 40]]}]}
     for extra in ([], [{"name": "/device:CUSTOM:Megascale Trace", "lines": []}]):
-        red = trace.reduce_profile(_profile([chip, window] + extra), (0.0, 100e-9))
+        red = trace.reduce_profile(_profile([chip, window] + extra), (0.0, 100e-9), [0])
         assert red.busy_s == pytest.approx(40e-9)
+
+
+def test_only_the_cells_chips_count():
+    """Planes of chips the cell does not own change nothing; over the
+    cell's chips busy time is averaged and idle gaps are the first chip's."""
+    window = {"name": "/host:CPU", "lines": [
+        {"name": "python3", "events": [["bench.window", 0, 100], ["bench.tick", 0, 60]]}]}
+
+    def chip(n, *events):
+        return {"name": f"/device:TPU:{n}", "lines": [
+            {"name": "XLA Ops", "events": [[f"%op{n}.1 = f32[2] add(...)", s, d]
+                                           for s, d in events]}]}
+
+    own = [chip(0, (10, 40))]
+    others = [chip(1, (0, 100)), chip(2), chip(3, (50, 20))]
+    alone = trace.reduce_profile(_profile(own + [window]), (0.0, 100e-9), [0])
+    for planes in (others + own + [window], own + others + [window]):
+        red = trace.reduce_profile(_profile(planes), (0.0, 100e-9), [0])
+        assert red.busy_s == alone.busy_s == pytest.approx(40e-9)
+        assert red.op_totals() == alone.op_totals()
+        assert red.breakdown() == alone.breakdown()
+    # four chips: chip 2 ran nothing and counts as idle
+    red = trace.reduce_profile(_profile(others + own + [window]), (0.0, 100e-9),
+                               [0, 1, 2, 3])
+    assert red.busy_s == pytest.approx((40 + 100 + 0 + 20) / 4 * 1e-9)
+    assert red.device_events == 3
+    # first chip (0) idle [0,10] [50,100]: 10 + 10 ns under bench.tick
+    assert dict(red.idle_by_span()) == pytest.approx(
+        {"bench.tick": 20e-9, trace.OUTSIDE: 40e-9})
+    # a cell on chips 1 and 3 only: chip 0's plane is left out
+    red = trace.reduce_profile(_profile(others + own + [window]), (0.0, 100e-9), [1, 3])
+    assert red.busy_s == pytest.approx(60e-9)
+    assert [n for n, _ in red.op_totals()] == ["op1", "op3"]

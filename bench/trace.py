@@ -1,9 +1,10 @@
 """Reduction of a profiler trace of the measured window to device numbers.
 
-Device work is the ``XLA Ops`` line of each chip's plane,
-``/device:TPU:<n>`` (one event per operation that ran, kernels
-included); other ``/device:`` planes, such as ``/device:CUSTOM:Megascale
-Trace``, are no chip and would halve the average. Host spans are the
+Device work is the ``XLA Ops`` line of the plane ``/device:TPU:<n>`` of
+each of the cell's chips, ``n`` its device id (one event per operation
+that ran, kernels included); the planes of the host's other chips, and
+other ``/device:`` planes such as ``/device:CUSTOM:Megascale Trace``, are
+not the cell's and would dilute the average. Host spans are the
 benchmark's own ``bench.*`` annotations. The ``bench.window`` span marks
 the measured window on the trace's clock, which also maps the host
 clock's tick times onto it. Everything is clipped to that window:
@@ -24,7 +25,7 @@ WINDOW = "bench.window"
 OUTSIDE = "outside bench spans"
 _NAME = re.compile(r"%?([A-Za-z_][\w\-.]*?)(?:\.\d+)? = ")
 _TARGET = re.compile(r'custom_call_target="([^"]+)"')
-_CHIP = re.compile(r"^/device:(TPU|GPU):\d+$")
+_CHIP = re.compile(r"^/device:(?:TPU|GPU):(\d+)$")
 
 
 def short_name(name: str) -> str:
@@ -78,7 +79,8 @@ def _subtract(iv, cut):
 class Reduced:
     """A trace reduced to device operations and benchmark spans.
 
-    ``devices``: per device plane, a list of (short name, start_ns, end_ns);
+    ``devices``: per chip of the cell, first chip first, a list of
+    (short name, start_ns, end_ns);
     ``spans``: benchmark span name -> list of (start_ns, end_ns);
     ``host_window``: the window's (start, end) on the host clock, seconds.
     """
@@ -137,7 +139,7 @@ class Reduced:
 
     def idle_by_span(self) -> list:
         """Idle device seconds by the innermost benchmark span covering
-        them (first device), largest first."""
+        them (on the cell's first chip), largest first."""
         if not self.busy:
             return []
         idle = _subtract([(self.lo, self.hi)], self.busy[0])
@@ -163,32 +165,35 @@ class Reduced:
         }
 
 
-def reduce_profile(profile, host_window) -> Reduced:
+def reduce_profile(profile, host_window, chip_ids) -> Reduced:
     """``profile``: a jax.profiler.ProfileData (or anything with planes,
-    lines and events of that shape)."""
-    devices, spans = [], {}
+    lines and events of that shape); ``chip_ids``: the device ids of the
+    cell's chips, first chip first. A chip with no plane ran nothing."""
+    chips, spans = {int(i): [] for i in chip_ids}, {}
     for plane in profile.planes:
-        if _CHIP.match(plane.name):
-            evs = []
+        chip = _CHIP.match(plane.name)
+        if chip:
+            evs = chips.get(int(chip.group(1)))
+            if evs is None:
+                continue
             for line in plane.lines:
                 if line.name == "XLA Ops":
                     evs += [(short_name(ev.name), ev.start_ns, ev.start_ns + ev.duration_ns)
                             for ev in line.events]
-            devices.append(evs)
         elif plane.name.startswith("/host:"):
             for line in plane.lines:
                 for ev in line.events:
                     if ev.name.startswith("bench."):
                         spans.setdefault(ev.name, []).append(
                             (ev.start_ns, ev.start_ns + ev.duration_ns))
-    return Reduced(devices, spans, host_window)
+    return Reduced(list(chips.values()), spans, host_window)
 
 
-def reduce_dir(tdir: str, host_window) -> Reduced | None:
+def reduce_dir(tdir: str, host_window, chip_ids) -> Reduced | None:
     """The reduced trace written under ``tdir``; None where there is none."""
     from jax.profiler import ProfileData
 
     files = glob.glob(f"{tdir}/**/*.xplane.pb", recursive=True)
     if not files:
         return None
-    return reduce_profile(ProfileData.from_file(files[0]), host_window)
+    return reduce_profile(ProfileData.from_file(files[0]), host_window, chip_ids)
