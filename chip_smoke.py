@@ -16,9 +16,10 @@
     16,384-device cohorts of a host arena through CohortFleetRuntime,
     a few ticks and one two-tier star merge, against the same
     reference over the full fleet.
-(4 chips) har widths, D=4096 sharded 4 ways: the kernel ingest, the
-    star psum merge and the ring(hops=2) ppermute halo merge, against
-    the one-chip fleet_train + fleet_merge on the same inputs.
+(4 chips) har widths, D=4096 laid out over the four chips, through
+    ``FleetRuntime`` ticks: the per-shard kernel ingest, the star psum
+    merge and the ring(hops=2) ppermute halo merge, against the plain
+    reference on one chip over the same windows and merge masks.
 
 Each phase prints one ``phase ...`` JSON line: the largest relative
 errors against the reference, the evidence that the kernels ran
@@ -298,72 +299,80 @@ def phase_paged(*, n_devices: int = 2**17, cohort: int = 16384,
 
 
 def phase_sharded(devices, *, n_devices: int = 4096, window: int = 8,
-                  seed: int = 0) -> dict:
-    """Four chips: the sharded har fleet vs one chip on the same inputs.
+                  ticks: int = 3, seed: int = 0) -> dict:
+    """Four chips: the har fleet laid out over them, through
+    ``FleetRuntime`` ticks (sharded kernel ingest, then a star psum
+    merge or a ring(hops=2) halo merge), against the plain reference on
+    one chip over the same windows and merge masks.
 
-    The inputs go through the host and each side's arrays are dropped
-    once its β is read back: the one-chip reference ring merge alone
-    needs 6.65 GB of temporaries next to the 2.6 GB fleet."""
+    The windows go through the host and each runtime's arrays are
+    dropped once its state is read back: the one-chip reference ring
+    merge alone needs 6.65 GB of temporaries next to the 2.6 GB fleet."""
     import jax
     import numpy as np
-    from jax.sharding import NamedSharding, PartitionSpec
 
     from repro.configs.oselm_edge import EDGE_CONFIGS
-    from repro.fleet import (
-        fleet_merge, fleet_merge_sharded, fleet_train, fleet_train_sharded,
-        init_fleet, ring, star,
-    )
-    from repro.fleet import sharded as sharded_mod
+    from repro.fleet import init_fleet, ring, star
     from repro.launch.mesh import make_mesh
     from repro.launch.sharding import shard_fleet
+    from repro.runtime import FleetRuntime, GovernorConfig, RuntimeConfig
 
     edge = EDGE_CONFIGS["har"]
     f, h, act, ridge = edge.n_features, edge.n_hidden, edge.activation, edge.ridge
     k_basis, k_boot, k_win = jax.random.split(jax.random.PRNGKey(seed), 3)
-    fleet, streams = jax.device_get((
-        init_fleet(k_basis, n_devices, f, h,
-                   jax.random.normal(k_boot, (n_devices, 2 * h, f)),
-                   activation=act, ridge=ridge),
-        jax.random.normal(k_win, (n_devices, window, f)),
-    ))
+    fleet = jax.device_get(init_fleet(
+        k_basis, n_devices, f, h, jax.random.normal(k_boot, (n_devices, 2 * h, f)),
+        activation=act, ridge=ridge))
+    windows = np.asarray(jax.random.normal(k_win, (ticks, n_devices, window, f)))
 
-    mesh = make_mesh((len(devices),), ("data",), devices=devices)
-    fleet_s = shard_fleet(fleet, mesh)
-    streams_s = jax.device_put(streams, NamedSharding(mesh, PartitionSpec("data")))
-    shards = sorted(
-        (s.index[0].start or 0, str(s.device))
-        for s in fleet_s.beta.addressable_shards
-    )
-    t0 = time.perf_counter()
-    trained_s = fleet_train_sharded(fleet_s, streams_s, mesh, kernel=True)
-    star_s = fleet_merge_sharded(trained_s, star(n_devices), mesh, ridge=ridge)
-    ring_s = fleet_merge_sharded(trained_s, ring(n_devices, hops=2), mesh,
-                                 ridge=ridge)
-    jax.block_until_ready((trained_s, star_s, ring_s))
-    first_call_s = time.perf_counter() - t0
-    got = jax.device_get((trained_s.beta, star_s.beta, ring_s.beta))
-    train_fn = next(fn for key, fn in sharded_mod._SHARDED_JIT_CACHE.items()
-                    if key[0] == "train")
-    train_c = train_fn.lower(fleet_s, streams_s).compile()
-    del fleet_s, streams_s, trained_s, star_s, ring_s
+    mesh = make_mesh((len(devices),), ("fleet",), devices=devices)
+    out = {"phase": "sharded_har", "devices": n_devices, "chips": len(devices),
+           "widths": [f, h]}
+    ref_ingest, ref_merge = _reference_fns()
+    for name, topo in (("star", star(n_devices)), ("ring", ring(n_devices, hops=2))):
+        runtime = FleetRuntime(shard_fleet(fleet, mesh, ("fleet",)), RuntimeConfig(
+            topology=topo, ridge=ridge, governor=GovernorConfig(merge_every=2)))
+        t0 = time.perf_counter()
+        runtime.warmup(window)
+        out[f"{name}_compile_s"] = time.perf_counter() - t0
+        out["shards"] = sorted(
+            (s.index[0].start or 0, str(s.device))
+            for s in runtime.states.beta.addressable_shards)
+        reports, masks = [], {}
+        for x in windows:
+            rep = runtime.tick(x)
+            reports.append(rep)
+            if rep.decision.merge:
+                masks[rep.tick] = runtime._merge_mask.copy()
+        zeros = runtime._put(np.zeros(n_devices, np.float32))
+        out[f"{name}_merge_custom_calls"] = _custom_calls(runtime._merge_fresh.lower(
+            runtime.states, zeros, *runtime._merge_operands).compile())
+        if name == "star":
+            ones = runtime._put(np.ones(n_devices, bool))
+            out["ingest_custom_calls"] = _custom_calls(runtime._ingest_detect.lower(
+                runtime.states, runtime.det, runtime._put(windows[0]),
+                runtime._put(False, replicated=True), ones, ones).compile())
+        got_p, got_beta = jax.device_get((runtime.states.p, runtime.states.beta))
+        out[f"{name}_merges"] = len(masks)
+        del runtime
 
-    fleet1, streams1 = jax.device_put((fleet, streams), jax.devices()[0])
-    with jax.default_matmul_precision("highest"):
-        trained = fleet_train(fleet1, streams1)
-        del fleet1, streams1
-        want = [np.asarray(trained.beta)]
-        for topo in (star(n_devices), ring(n_devices, hops=2)):
-            want.append(np.asarray(fleet_merge(trained, topo, ridge=ridge).beta))
-    return {
-        "phase": "sharded_har",
-        "devices": n_devices, "chips": len(devices), "widths": [f, h],
-        "shards": [{"first_device": lo, "chip": dev} for lo, dev in shards],
-        "first_call_s": first_call_s,
-        "ingest_custom_calls": _custom_calls(train_c),
-        "train_beta_rel_err": _rel(got[0], want[0]),
-        "star_beta_rel_err": _rel(got[1], want[1]),
-        "ring_beta_rel_err": _rel(got[2], want[2]),
-    }
+        one = jax.devices()[0]
+        p, beta = jax.device_put((fleet.p, fleet.beta), one)
+        alpha, bias = jax.device_put((fleet.params.alpha[0], fleet.params.bias[0]), one)
+        loss_errs = []
+        with jax.default_matmul_precision("highest"):
+            for rep, x in zip(reports, windows):
+                p, beta, losses = ref_ingest(p, beta, alpha, bias, jax.device_put(x, one),
+                                             np.ones(n_devices, bool), act)
+                loss_errs.append(_loss_rel(rep.losses, losses))
+                if rep.decision.merge:
+                    p, beta = ref_merge(p, beta, alpha, bias, masks[rep.tick],
+                                        topo, ridge, act)
+        out[f"{name}_beta_rel_err"] = _rel(got_beta, beta)
+        out[f"{name}_p_rel_err"] = _rel(got_p, p)
+        out[f"{name}_loss_rel_err"] = max(loss_errs)
+        del p, beta
+    return out
 
 
 # ------------------------------------------------------------------ checks

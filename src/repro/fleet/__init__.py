@@ -64,8 +64,8 @@ from repro.fleet.quantize import (
 )
 from repro.fleet.sharded import (
     cohort_tree_reduce,
-    fleet_merge_sharded,
-    fleet_train_sharded,
+    sharded_ingest,
+    sharded_merge,
 )
 from repro.fleet.partition import (
     DriftEvent,
@@ -94,9 +94,8 @@ __all__ = [
     "topology_round_cost",
     "device_state", "fleet_from_uv", "fleet_merge", "fleet_merge_kernel",
     "fleet_merge_masked", "fleet_merge_masked_kernel", "fleet_merge_quantized",
-    "fleet_merge_sharded",
     "fleet_to_uv", "fleet_score", "fleet_train", "fleet_train_rounds",
-    "fleet_train_sharded", "init_fleet",
+    "init_fleet", "sharded_ingest", "sharded_merge",
     "apply_codec", "dequantize_tiles", "init_residual",
     "payload_precision_nbytes", "quantize_roundtrip", "quantize_tiles",
     "DriftEvent", "FleetStreams", "make_fleet_streams", "random_drift_schedule",

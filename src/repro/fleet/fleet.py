@@ -141,7 +141,7 @@ def fleet_train(
     provides; this function is itself jitted, so the shared-basis
     precondition is validated at the concrete entry points
     (``fleet_ingest`` called directly, ``fleet_train_rounds``,
-    ``fleet_train_sharded``, ``FleetRuntime``) rather than here."""
+    ``FleetRuntime``) rather than here."""
     streams = jnp.asarray(streams)
     if kernel:
         from repro.kernels.fleet_ingest import fleet_ingest

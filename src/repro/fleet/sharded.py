@@ -2,41 +2,39 @@
 
 `repro.fleet` simulates the whole fleet as one stacked pytree;
 `repro.federated.mesh_federation` runs one device per mesh shard. This
-module combines them: the stacked device axis is sharded across mesh
-devices (``repro.launch.sharding.fleet_shardings``), and both halves
-of a federation round run shard-locally:
+module combines them: the stacked device axis is laid out by device
+range over a mesh axis (``fleet_layout``; ``repro.launch.sharding``),
+and both halves of a federation round run shard-locally. These are the
+per-shard programs ``FleetRuntime`` ticks a fleet laid out over several
+chips with:
 
-- ``fleet_train_sharded`` — per-tick ingest is embarrassingly parallel
-  over devices, so the vmap+scan train loop (or the fused
-  ``fleet_ingest`` kernel family, ``kernel=True``) runs under
-  ``shard_map`` with NO collectives at all: each shard trains only its
-  resident devices, which is the multi-host deployment where ticks
-  arrive per-shard (the ROADMAP's multi-host-ingest item).
-- ``fleet_merge_sharded`` — each shard segment-sums its *local*
-  devices' (U, V) into per-cluster partials, and ONE ``jax.lax.psum``
-  of the (n_clusters, Ñ, Ñ+m) partials completes Eq. 8 globally — the
-  per-shard collective is O(clusters), never O(devices), which is what
-  lets a 10k-device fleet merge over a handful of TPU shards without
-  all-gathering 10k payloads. Cluster-wise-constant topologies (star,
-  hierarchical, all-to-all, closed ring) take that psum path; the open
-  ring takes a **halo exchange**: each shard ``ppermute``s its ``hops``
-  edge (U, V) payload blocks to the adjacent shards (O(hops) payloads
-  per shard, never the fleet), then forms its devices' banded neighbor
-  sums from the extended local block — so banded merges compose with
-  sharded fleets end-to-end.
+- ``sharded_ingest`` — per-tick ingest is embarrassingly parallel over
+  devices, so the vmap+scan train loop (or the fused ``fleet_ingest``
+  kernel family, ``kernel=True``) runs under ``shard_map`` with NO
+  collectives at all: each shard trains only its resident devices and
+  returns their pre-train scores.
+- ``sharded_merge`` — the masked Eq. 8 merge. Cluster-wise-constant
+  topologies (star, hierarchical, all-to-all, closed ring) segment-sum
+  each shard's *local* payloads into per-cluster partials, and ONE
+  ``jax.lax.psum`` of the (n_clusters, Ñ, Ñ+m) partials completes the
+  sum — O(clusters) per shard, never O(devices). The open ring takes a
+  **halo exchange**: each shard ``ppermute``s its ``hops`` edge payloads
+  to the adjacent shards, then solves its devices over the extended
+  block (``banded_merge_solve(halo=True)``).
 """
 from __future__ import annotations
 
+from functools import partial
 from typing import Sequence
 
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.sharding import Mesh, PartitionSpec as P
+from jax.sharding import AxisType, Mesh, NamedSharding, PartitionSpec as P
 
-from repro.core import OSELMState
+from repro.core import UV, OSELMState, ae_score
 from repro.federated.compat import revary, shard_map_compat as _shard_map
-from repro.fleet.fleet import _bcast, _fleet_train, _solve_uv, fleet_to_uv
+from repro.fleet.fleet import _bcast, _fleet_train, _solve_uv, fleet_from_uv, fleet_to_uv
 from repro.fleet.topology import Topology
 
 
@@ -62,10 +60,9 @@ def _mesh_axis_size(mesh: Mesh, axes: Sequence[str]) -> int:
     return int(np.prod([mesh.shape[a] for a in axes]))
 
 
-# jitted shard_map callables keyed by their closure parameters —
-# jax.jit's cache is keyed on the function OBJECT, so wrapping a fresh
-# closure per call would re-trace/re-compile every tick of the serve
-# loop these functions are the hot path of
+# jitted cohort reductions keyed by their closure parameters — jax.jit's
+# cache is keyed on the function OBJECT, so wrapping a fresh closure per
+# call would re-trace/re-compile every merge round
 _SHARDED_JIT_CACHE: dict[tuple, object] = {}
 
 
@@ -76,137 +73,192 @@ def _cached_sharded_jit(key: tuple, build):
     return fn
 
 
-def fleet_train_sharded(
+def fleet_layout(states: OSELMState) -> tuple[Mesh, str] | None:
+    """The mesh and axis a stacked fleet is laid out over by device
+    range, or None for a fleet on one device. A fleet over several
+    devices must be split along its device axis over exactly one mesh
+    axis, of ``Auto`` type, that spans them all (what ``shard_fleet``
+    over a 1-D ``repro.launch.mesh.make_mesh`` and the benchmark's
+    ``lay_out`` give)."""
+    sharding = getattr(states.beta, "sharding", None)
+    if sharding is None or len(sharding.device_set) < 2:
+        return None
+    spec = sharding.spec if isinstance(sharding, NamedSharding) else ()
+    axis = spec[0] if spec else None
+    if isinstance(axis, tuple) and len(axis) == 1:
+        axis = axis[0]
+    mesh = getattr(sharding, "mesh", None)
+    if (not isinstance(axis, str) or any(s is not None for s in spec[1:])
+            or mesh.shape[axis] != len(sharding.device_set)
+            or mesh.axis_types[mesh.axis_names.index(axis)] != AxisType.Auto):
+        raise ValueError(
+            "a fleet over several devices must be laid out by device range "
+            f"over one mesh axis of Auto type (repro.launch.mesh.make_mesh); got {sharding}"
+        )
+    return mesh, axis
+
+
+def ingest_local(
     states: OSELMState,
-    streams: jnp.ndarray,
-    mesh: Mesh,
-    axes: Sequence[str] = ("data",),
+    window: jnp.ndarray,
     *,
-    kernel: bool = False,
+    kernel: bool,
     backend: str = "auto",
     interpret: bool | None = None,
-) -> OSELMState:
-    """Per-shard tick ingest of a mesh-sharded stacked fleet.
-
-    ``states`` leaves and ``streams`` (D, T, n) carry a leading device
-    axis sharded over ``axes``; every shard trains only its local
-    devices (k=1 sequential updates) — zero collectives, so ingest
-    scales linearly with shard count. ``kernel=True`` runs each shard's
-    local ingest through the fused ``fleet_ingest`` family, same
-    dispatch as ``fleet_train(kernel=True)``. Returns the trained fleet
-    with the same sharding.
-    """
-    n_shards = _mesh_axis_size(mesh, axes)
-    n_dev = states.beta.shape[0]
-    if n_dev % n_shards:
-        raise ValueError(f"n_devices={n_dev} not divisible by {n_shards} shards")
-    spec = P(tuple(axes))
-    from repro.kernels.fleet_ingest import resolve_backend, validate_shared_basis
-
+) -> tuple[OSELMState, jnp.ndarray]:
+    """One tick's ingest of the devices at hand: (trained fleet, (D,)
+    mean pre-train score of each device's window). ``kernel`` runs the
+    fused ``fleet_ingest`` family; otherwise the score under the current
+    model, then the k=1 sequential updates (``_fleet_train``)."""
     if kernel:
-        validate_shared_basis(states)  # concrete here, pre-shard_map
+        from repro.kernels.fleet_ingest import fleet_ingest
+
+        return fleet_ingest(states, window, backend=backend, interpret=interpret)
+    losses = jax.vmap(lambda s, xb: jnp.mean(ae_score(s, xb)))(states, window)
+    return _fleet_train(states, window), losses
+
+
+def sharded_ingest(
+    mesh: Mesh,
+    axes: Sequence[str],
+    *,
+    kernel: bool,
+    backend: str = "auto",
+    interpret: bool | None = None,
+):
+    """``(fleet, window) -> (trained, losses)`` with every shard ingesting
+    only its own devices (``ingest_local`` under ``shard_map``): no
+    collectives, and each Pallas call sees one shard's block, so none is
+    left to the automatic partitioner."""
+    from repro.kernels.fleet_ingest import resolve_backend
+
+    spec = P(tuple(axes))
     resolved = resolve_backend(backend)
 
-    def build():
-        def body(st: OSELMState, xs: jnp.ndarray) -> OSELMState:
-            if kernel:
-                from repro.kernels.fleet_ingest import fleet_ingest
+    def body(st: OSELMState, xs: jnp.ndarray):
+        return ingest_local(st, xs, kernel=kernel, backend=resolved, interpret=interpret)
 
-                st, _ = fleet_ingest(st, xs, backend=resolved, interpret=interpret)
-            else:
-                st = _fleet_train(st, xs)
-            return st
-
-        return _shard_map(
-            body, mesh, in_specs=(spec, spec), out_specs=spec,
-            # pallas_call has no shard_map replication rule; the body is
-            # per-shard-local anyway (no collectives), so the check adds
-            # nothing here
-            check_rep=not (kernel and resolved == "pallas"),
-        )
-
-    fn = _cached_sharded_jit(
-        ("train", mesh, tuple(axes), kernel, resolved, interpret), build
+    return _shard_map(
+        body, mesh, in_specs=(spec, spec), out_specs=(spec, spec),
+        # pallas_call has no shard_map replication rule; the body is
+        # per-shard-local anyway (no collectives), so the check adds
+        # nothing here
+        check_rep=not (kernel and resolved == "pallas"),
     )
-    return fn(states, jnp.asarray(streams))
 
 
-def _halo_ring_merge_body(
-    st: OSELMState,
-    axis: str,
-    n_shards: int,
-    hops: int,
-    ridge: float,
-) -> OSELMState:
-    """Open-ring merge of one shard's local devices with a halo
-    exchange: ``ppermute`` ships the ``hops`` edge payload blocks to
-    each neighboring shard (the only cross-shard traffic — O(hops)
-    payloads per shard), after which every local device's ≤2·hops+1
-    banded neighbor sum is shard-local. Devices are laid out
+def halo_bytes(topology: Topology, n_shards: int, n_hidden: int, n_out: int) -> int:
+    """Bytes one merge round ships between shards, from the shapes: an
+    open ring's 2·hops edge payloads per shard, else each shard's
+    cluster partials into the all-reduce; 0 on one shard. A payload is
+    one device's [U | V], Ñ·(Ñ+m) float32."""
+    if n_shards < 2:
+        return 0
+    payload = 4 * n_hidden * (n_hidden + n_out)
+    if topology.kind == "banded" and not topology.band_closed:
+        return 2 * topology.hops * n_shards * payload
+    _, n_clusters, _ = _merge_cids(topology)
+    return n_shards * n_clusters * payload
+
+
+def _keep(st: OSELMState, p, beta, mask, axes) -> OSELMState:
+    """Devices with mask 0 keep their own (P, β)."""
+    keep = (mask > 0)[:, None, None]
+    return st.replace(
+        beta=jnp.where(keep, revary(beta.astype(st.beta.dtype), axes), st.beta),
+        p=jnp.where(keep, revary(p.astype(st.p.dtype), axes), st.p),
+    )
+
+
+def _ring_merge_body(st, mask, *, axis, n_shards, hops, ridge, kernel, interpret):
+    """One shard's masked open-ring merge: mask the payloads, ship the
+    ``hops`` edge payloads to each neighbouring shard (``ppermute``; the
+    ring closes from the last shard to the first), then solve the
+    shard's devices over the extended block. Devices are laid out
     contiguously per shard (device d lives on shard d // L), so the
     global ring order is (shard, local) lexicographic."""
     uv = fleet_to_uv(st, ridge=ridge)
-    w = jnp.concatenate([uv.u, uv.v], axis=2)  # (L, Ñ, Ñ+m) local payloads
-    if hops > 0:
-        fwd = [(i, (i + 1) % n_shards) for i in range(n_shards)]
-        bwd = [(i, (i - 1) % n_shards) for i in range(n_shards)]
-        # halo from the LEFT neighbor shard: its last `hops` devices
-        left = jax.lax.ppermute(w[-hops:], axis, fwd)
-        # halo from the RIGHT neighbor shard: its first `hops` devices
-        right = jax.lax.ppermute(w[:hops], axis, bwd)
-        ext = jnp.concatenate([left, w, right], axis=0)  # (L + 2·hops, ...)
-    else:  # hops=0 band: each device merges only itself (w[-0:] would
-        ext = w  # be the WHOLE block, shipping a bogus full-shard halo)
-    n_local = w.shape[0]
-    mixed = ext[:n_local]
-    for off in range(1, 2 * hops + 1):
-        mixed = mixed + ext[off : off + n_local]
     n = uv.u.shape[1]
-    p, beta = jax.vmap(lambda u, v: _solve_uv(u, v, ridge))(
-        mixed[:, :, :n], mixed[:, :, n:]
-    )
-    return st.replace(
-        beta=revary(beta.astype(st.beta.dtype), (axis,)),
-        p=revary(p.astype(st.p.dtype), (axis,)),
-    )
+    w = jnp.concatenate([uv.u, uv.v], axis=2) * mask.astype(uv.u.dtype)[:, None, None]
+    if hops > 0:  # w[-0:] would be the WHOLE block, a bogus full-shard halo
+        left = jax.lax.ppermute(w[-hops:], axis, [(i, (i + 1) % n_shards)
+                                                  for i in range(n_shards)])
+        right = jax.lax.ppermute(w[:hops], axis, [(i, (i - 1) % n_shards)
+                                                  for i in range(n_shards)])
+        ext = jnp.concatenate([left, w, right], axis=0)  # (L + 2·hops, ...)
+    else:
+        ext = w
+    n_local = w.shape[0]
+    if kernel:
+        from repro.kernels.topology_merge import banded_merge_solve
+
+        p, beta = banded_merge_solve(ext, hops, ridge=ridge, halo=True,
+                                     interpret=interpret)
+    else:
+        # the one-device ring's order of summation (Topology.mix)
+        mixed = sum(ext[hops - o:hops - o + n_local] for o in range(-hops, hops + 1))
+        merged = fleet_from_uv(st, UV(u=mixed[:, :, :n], v=mixed[:, :, n:]), ridge=ridge)
+        p, beta = merged.p, merged.beta
+    return _keep(st, p, beta, mask, (axis,))
 
 
-def fleet_merge_sharded(
-    states: OSELMState,
+def _segment_merge_body(st, mask, cids, *, axes, n_clusters, isolated, ridge,
+                        kernel, interpret):
+    """One shard's masked cluster merge: its devices' masked payloads
+    summed per cluster, one ``psum`` of the O(clusters) partials, then
+    the solve (once for a fleet-wide sum, per cluster otherwise)."""
+    uv = fleet_to_uv(st, ridge=ridge)
+    n = uv.u.shape[1]
+    w = jnp.concatenate([uv.u, uv.v], axis=2)
+    mf = mask.astype(w.dtype)
+    if kernel:
+        from repro.kernels.topology_merge import (
+            _masked_segment_sum_mix_call,
+            from_uv_solve,
+        )
+
+        sums = _masked_segment_sum_mix_call(w, cids, mf, n_clusters, interpret=interpret)
+        # the kernel writes only the clusters this shard holds
+        held = jax.ops.segment_sum(jnp.ones_like(mf), cids, num_segments=n_clusters) > 0
+        sums = jnp.where(held[:, None, None], sums, 0.0)
+        solve = lambda s: from_uv_solve(  # noqa: E731
+            s[:, :, :n], s[:, :, n:], ridge=ridge, interpret=interpret)
+    else:
+        sums = jax.ops.segment_sum(w * mf[:, None, None], cids, num_segments=n_clusters)
+        solve = lambda s: jax.vmap(lambda u, v: _solve_uv(u, v, ridge))(  # noqa: E731
+            s[:, :, :n], s[:, :, n:])
+    sums = jax.lax.psum(sums, axes)  # O(clusters) per-shard collective
+    if isolated:
+        pc, betac = solve(sums)
+        p, beta = pc[cids], betac[cids]
+    else:
+        p1, beta1 = solve(sums.sum(0, keepdims=True))
+        p, beta = _bcast(p1[0], cids.shape[0]), _bcast(beta1[0], cids.shape[0])
+    return _keep(st, p, beta, mask, axes)
+
+
+def sharded_merge(
     topology: Topology,
     mesh: Mesh,
-    axes: Sequence[str] = ("data",),
+    axes: Sequence[str],
+    ridge: float,
     *,
-    ridge: float = 0.0,
-) -> OSELMState:
-    """Cooperative update of a mesh-sharded stacked fleet.
-
-    ``states`` leaves carry a leading device axis sharded over ``axes``
-    (shard it with ``repro.launch.sharding.shard_fleet``). Each shard
-    computes local per-cluster (U, V) partial sums, one psum of the
-    O(clusters)-sized partials completes the Eq. 8 sum, and each shard
-    solves + broadcasts locally. Open-ring (banded) topologies instead
-    take the halo-exchange path: ``ppermute`` of the ``hops`` edge
-    payload blocks between adjacent shards, then shard-local banded
-    sums + per-device solves. Returns the merged fleet with the same
-    sharding.
-    """
-    fn, operands = _sharded_merge_program(topology, mesh, tuple(axes), ridge)
-    return fn(states, *operands)
-
-
-def _sharded_merge_program(
-    topology: Topology, mesh: Mesh, axes: tuple[str, ...], ridge: float
+    kernel: bool,
+    interpret: bool | None = None,
 ) -> tuple:
-    """The jitted shard_map program of one sharded merge, and the
-    operands it takes after the fleet."""
+    """The masked Eq. 8 merge of a fleet laid out over ``axes``, each
+    shard solving only its own devices: ``(fn, operands)`` with
+    ``fn(fleet, mask, *operands)`` under ``shard_map``. An open ring
+    exchanges halos (``_ring_merge_body``); cluster-wise-constant
+    topologies psum their partials (``_segment_merge_body``). ``kernel``
+    runs the topology-merge kernels per shard."""
+    axes = tuple(axes)
     n_shards = _mesh_axis_size(mesh, axes)
     if topology.n_devices % n_shards:
         raise ValueError(
             f"n_devices={topology.n_devices} not divisible by {n_shards} shards"
         )
-    spec = P(tuple(axes))
-
+    spec = P(axes)
     if topology.kind == "banded" and not topology.band_closed:
         if len(axes) != 1:
             raise NotImplementedError(
@@ -219,42 +271,20 @@ def _sharded_merge_program(
                 f"shard ({n_local}): a wider band straddles non-adjacent "
                 "shards — use fewer shards or the single-process merge"
             )
-        fn = _cached_sharded_jit(
-            ("halo", mesh, axes, topology, ridge),
-            lambda: _shard_map(
-                lambda st: _halo_ring_merge_body(
-                    st, axes[0], n_shards, topology.hops, ridge
-                ),
-                mesh, in_specs=(spec,), out_specs=spec,
-            ),
-        )
-        return fn, ()
-
-    cids, n_clusters, isolated = _merge_cids(topology)
-
-    def body(st: OSELMState, cids_local: jnp.ndarray) -> OSELMState:
-        n_local = cids_local.shape[0]
-        uv = fleet_to_uv(st, ridge=ridge)  # this shard's devices only
-        su = jax.ops.segment_sum(uv.u, cids_local, num_segments=n_clusters)
-        sv = jax.ops.segment_sum(uv.v, cids_local, num_segments=n_clusters)
-        su = jax.lax.psum(su, axes)  # O(clusters) per-shard collective
-        sv = jax.lax.psum(sv, axes)
-        if isolated:
-            pc, betac = jax.vmap(lambda u, v: _solve_uv(u, v, ridge))(su, sv)
-            p, beta = pc[cids_local], betac[cids_local]
-        else:
-            p1, beta1 = _solve_uv(su.sum(0), sv.sum(0), ridge)
-            p, beta = _bcast(p1, n_local), _bcast(beta1, n_local)
-        return st.replace(
-            beta=revary(beta.astype(st.beta.dtype), axes),
-            p=revary(p.astype(st.p.dtype), axes),
-        )
-
-    fn = _cached_sharded_jit(
-        ("merge", mesh, axes, topology, ridge),
-        lambda: _shard_map(body, mesh, in_specs=(spec, spec), out_specs=spec),
-    )
-    return fn, (jnp.asarray(cids),)
+        body = partial(_ring_merge_body, axis=axes[0], n_shards=n_shards,
+                       hops=topology.hops, ridge=ridge, kernel=kernel,
+                       interpret=interpret)
+        in_specs, operands = (spec, spec), ()
+    else:
+        cids, n_clusters, isolated = _merge_cids(topology)
+        body = partial(_segment_merge_body, axes=axes, n_clusters=n_clusters,
+                       isolated=isolated, ridge=ridge, kernel=kernel,
+                       interpret=interpret)
+        in_specs = (spec, spec, spec)
+        operands = (np.asarray(cids, np.int32),)
+    # pallas_call has no shard_map replication rule
+    fn = _shard_map(body, mesh, in_specs=in_specs, out_specs=spec, check_rep=not kernel)
+    return fn, operands
 
 
 # ------------------------------------------- inter-cohort tier-2 reduction

@@ -475,22 +475,33 @@ def _banded_solve_kernel(*refs, n: int, n_p: int, w_p: int, n_off: int, ridge: f
     b_ref[0] = jnp.pad(w[:, n_p + n : n_p + n + m], ((0, 0), (0, b_ref.shape[-1] - m)))
 
 
-@functools.partial(jax.jit, static_argnames=("hops", "ridge", "interpret"))
+@functools.partial(jax.jit, static_argnames=("hops", "ridge", "halo", "interpret"))
 def banded_merge_solve(
     w: jnp.ndarray,
     hops: int,
     *,
     ridge: float = 0.0,
+    halo: bool = False,
     interpret: bool | None = None,
 ) -> tuple[jnp.ndarray, jnp.ndarray]:
     """The fused ring hot path: ``w`` is the stacked [U | V] payload
     (D, Ñ, Ñ+m) — Ñ is read off the row dimension; one kernel
     invocation per device gathers its ≤2·hops+1 neighbor blocks, sums
     them in VMEM, and solves for (P, β) in place.
+
+    ``halo=True`` takes one shard's block already extended by ``hops``
+    neighbour rows on each side (L + 2·hops, Ñ, Ñ+m) and solves its L
+    centre devices: the neighbours of centre row i are rows i..i+2·hops,
+    with no wrap (the ring closes across shards, outside the kernel).
     """
     d, n, nm = w.shape
     m = nm - n
-    if 2 * hops + 1 > d:
+    if halo:
+        d -= 2 * hops
+        if d < 1:
+            raise ValueError(f"a halo block of {w.shape[0]} rows has no centre "
+                             f"rows at hops={hops}")
+    elif 2 * hops + 1 > d:
         raise ValueError(f"band 2*{hops}+1 exceeds n_devices={d}; use a full-sum path")
     n_off = 2 * hops + 1
     n_p = _pad_up(n, _SUBLANE)
@@ -504,10 +515,14 @@ def banded_merge_solve(
     )  # (D, n_p, n_p + m)
     p_cols = _pad_up(n, _LANE)
     b_cols = _pad_up(m, _LANE)
-    specs = [
-        pl.BlockSpec((1, n_p, n_p + m), lambda i, o=o: ((i + o - hops) % d, 0, 0))
-        for o in range(n_off)
-    ]
+    if halo:
+        specs = [pl.BlockSpec((1, n_p, n_p + m), lambda i, o=o: (i + o, 0, 0))
+                 for o in range(n_off)]
+    else:
+        specs = [
+            pl.BlockSpec((1, n_p, n_p + m), lambda i, o=o: ((i + o - hops) % d, 0, 0))
+            for o in range(n_off)
+        ]
     p_out, b_out = pl.pallas_call(
         functools.partial(
             _banded_solve_kernel, n=n, n_p=n_p, w_p=w_p, n_off=n_off, ridge=ridge

@@ -115,9 +115,9 @@ def fleet_stack_spec(axes: tuple[str, ...] = ("data",)) -> P:
 
 def fleet_shardings(states: PyTree, mesh: Mesh, axes: tuple[str, ...] = ("data",)) -> PyTree:
     """NamedShardings for a stacked ``OSELMState`` fleet: every leaf's
-    leading device axis lands on ``axes``; used with
-    ``repro.fleet.sharded.fleet_merge_sharded`` so the topology merge
-    lowers to a psum of O(clusters) segment sums per shard."""
+    leading device axis lands on ``axes``; ``FleetRuntime`` ticks a
+    fleet laid out so per shard (``repro.fleet.sharded``), its topology
+    merge a psum of O(clusters) segment sums or a ring halo exchange."""
     sharding = NamedSharding(mesh, fleet_stack_spec(axes))
     return jax.tree.map(lambda _: sharding, states)
 

@@ -102,6 +102,11 @@ class TelemetrySink:
             "merge_bytes_total", "merge payload traffic by wire precision",
             labels=("precision",),
         )
+        self.merge_halo_bytes = r.counter(
+            "merge_halo_bytes_total",
+            "merge payload bytes shipped between chips (halo rows or psum "
+            "partials of a fleet laid out over several; 0 on one)",
+        )
         # ---- cohort-paging catalog (the million-device arena runtime;
         # zero-valued for the resident runtime, same registry so both
         # runtimes share one exposition surface)

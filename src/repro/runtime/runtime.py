@@ -41,13 +41,13 @@ from pathlib import Path
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.sharding import NamedSharding, PartitionSpec
 
 from repro.checkpoint import CheckpointManager
-from repro.core import UV, OSELMState, ae_score
+from repro.core import UV, OSELMState
 from repro.federated.selection import FleetMaskFn
 from repro.fleet.faults import FaultInjector
 from repro.fleet.fleet import (
-    _fleet_train,
     _masked_kernel_merge_from_w,
     _masked_merge_body,
     _quantized_merge_body,
@@ -61,9 +61,16 @@ from repro.fleet.robust import (
     finite_payload_mask,
     robust_merge_from_w,
 )
+from repro.fleet.sharded import (
+    fleet_layout,
+    halo_bytes,
+    ingest_local,
+    sharded_ingest,
+    sharded_merge,
+)
 from repro.fleet.staleness import StalenessSchedule, _lagged_gather
 from repro.fleet.topology import Topology
-from repro.kernels.fleet_ingest import fleet_ingest, native_kernels
+from repro.kernels.fleet_ingest import native_kernels
 from repro.obs import TelemetryConfig, TelemetrySink, trace
 from repro.runtime.detector import (
     DetectorConfig,
@@ -153,8 +160,38 @@ def _where_served(keep: jnp.ndarray, new, old):
     )
 
 
+def ingest_detect_program(ingest, det_cfg: DetectorConfig):
+    """The tick's ingest + detect: ``ingest(fleet, batch) -> (trained,
+    losses)`` (per shard on a sharded fleet), then the detector over the
+    whole fleet's losses, so the post-merge rebase median is fleet-wide
+    on any layout."""
+
+    def ingest_detect(fleet, det, batch, rebase, participants, served):
+        trained, losses = ingest(fleet, batch)
+        det_new, drifted, fresh = detector_update(
+            det, losses, det_cfg, rebase=rebase, participants=participants
+        )
+        # un-served devices (padded rows of a partially-filled serving
+        # window) keep model AND detector state — the served mask is a
+        # traced operand, so partial windows never retrace; all-ones
+        # served is bit-for-bit the unmasked path
+        keep = served.astype(bool)
+        fleet = _where_served(keep, trained, fleet)
+        det = _where_served(keep, det_new, det)
+        return fleet, det, losses, det.drifted, fresh & keep
+
+    return ingest_detect
+
+
 class FleetRuntime:
-    """A live fleet: stacked OS-ELM states + detector bank + governor."""
+    """A live fleet: stacked OS-ELM states + detector bank + governor.
+
+    A fleet laid out by device range over several chips (its leaves
+    carry a ``NamedSharding`` over one mesh axis; ``fleet_layout``) runs
+    the same tick per shard: each chip gets only its slice of the window,
+    ingests its own devices (``sharded_ingest``) and merges them after a
+    halo exchange or a psum (``sharded_merge``); the detector runs over
+    the whole fleet."""
 
     def __init__(
         self,
@@ -194,9 +231,33 @@ class FleetRuntime:
                 f"fault injector is for {config.faults.n_devices} devices, "
                 f"fleet has {n_devices}"
             )
+        # a fleet laid out by device range over several chips runs its
+        # tick per shard (sharded ingest, halo or psum merge)
+        layout = fleet_layout(states)
+        if layout is not None:
+            unsupported = [name for name, on in (
+                ("staleness", config.staleness is not None),
+                ("robust", config.robust is not None),
+                ("faults", config.faults is not None),
+                ("payload_precision", config.payload_precision != "f32"),
+                ("snapshot_dir", config.snapshot_dir is not None),
+            ) if on]
+            if unsupported:
+                raise ValueError(
+                    f"a fleet laid out over {layout[0].shape[layout[1]]} devices "
+                    f"runs exact f32 merges of fresh payloads without snapshots; "
+                    f"not supported there: {', '.join(unsupported)}"
+                )
         self.states = states
         self.config = config
-        self.det = init_detector(n_devices)
+        self._by_device = self._replicated = None
+        self.shards = 1
+        if layout is not None:
+            mesh, axis = layout
+            self._by_device = NamedSharding(mesh, PartitionSpec(axis))
+            self._replicated = NamedSharding(mesh, PartitionSpec())
+            self.shards = mesh.shape[axis]
+        self.det = self._put(init_detector(n_devices))
         # NB: on the stacked fleet pytree beta is (D, Ñ, m), so the
         # scalar-state n_hidden/n_out properties would read (D, Ñ)
         n_hidden, n_out = states.beta.shape[1], states.beta.shape[2]
@@ -218,6 +279,7 @@ class FleetRuntime:
             TelemetrySink(config.telemetry)
             if config.telemetry is not None else None
         )
+        self._merge_operands = ()  # the sharded merge's cluster ids
         self._tick_inputs: np.ndarray | None = None  # last post-poison batch,
                                                      # carried for flight dumps
         self.ckpt = (
@@ -232,49 +294,28 @@ class FleetRuntime:
         native = native_kernels()
         use_merge_kernel = config.use_merge_kernel or native
 
-        if config.use_ingest_kernel or native:
+        use_ingest_kernel = config.use_ingest_kernel or native
+        if use_ingest_kernel:
             from repro.kernels.fleet_ingest import validate_shared_basis
 
             # the tick is jitted (tracers inside), so the kernel-ingest
             # shared-basis precondition is checked once, here, while the
             # fleet is concrete
             validate_shared_basis(states)
-
-            def ingest_detect(fleet, det, batch, rebase, participants, served):
-                # the fused ingest family computes the pre-train drift
-                # signal and the k=1 window updates in ONE pass over the
-                # batch ((P, β) resident across the window) — same
-                # losses the two-pass reference produces
-                trained, losses = fleet_ingest(
-                    fleet, batch, backend=config.ingest_backend
-                )
-                det_new, drifted, fresh = detector_update(
-                    det, losses, det_cfg, rebase=rebase, participants=participants
-                )
-                # un-served devices (padded rows of a partially-filled
-                # serving window) keep model AND detector state — the
-                # served mask is a traced operand, so partial windows
-                # never retrace; all-ones served is bit-for-bit the
-                # unmasked path
-                keep = served.astype(bool)
-                fleet = _where_served(keep, trained, fleet)
-                det = _where_served(keep, det_new, det)
-                return fleet, det, losses, det.drifted, fresh & keep
+        if layout is not None:
+            ingest = sharded_ingest(mesh, (axis,), kernel=use_ingest_kernel,
+                                    backend=config.ingest_backend)
         else:
-            def ingest_detect(fleet, det, batch, rebase, participants, served):
-                # score BEFORE training: the loss of the incoming data under
-                # the current model is the drift signal (§3.4 / 2203.01077)
-                losses = jax.vmap(lambda s, xb: jnp.mean(ae_score(s, xb)))(fleet, batch)
-                trained = _fleet_train(fleet, batch)  # k=1 sequential updates
-                det_new, drifted, fresh = detector_update(
-                    det, losses, det_cfg, rebase=rebase, participants=participants
-                )
-                keep = served.astype(bool)
-                fleet = _where_served(keep, trained, fleet)
-                det = _where_served(keep, det_new, det)
-                return fleet, det, losses, det.drifted, fresh & keep
+            # the fused ingest family computes the pre-train drift signal
+            # and the k=1 window updates in ONE pass over the batch ((P, β)
+            # resident across the window); without it the loss of the
+            # incoming data under the current model is scored BEFORE
+            # training (§3.4 / 2203.01077) — the same losses either way
+            def ingest(fleet, batch):
+                return ingest_local(fleet, batch, kernel=use_ingest_kernel,
+                                    backend=config.ingest_backend)
 
-        self._ingest_detect = jax.jit(ingest_detect)
+        self._ingest_detect = self._jit(ingest_detect_program(ingest, det_cfg))
         # first tick after a merge: participants' bands rebase common-mode
         self._post_merge = False
         self._merge_mask = np.ones(n_devices, bool)
@@ -297,6 +338,10 @@ class FleetRuntime:
                     fleet, topology, residual, precision, ridge,
                     mask, fp_mask, use_merge_kernel, None,
                 )
+        elif layout is not None:
+            merge_fresh, operands = sharded_merge(
+                topology, mesh, (axis,), ridge, kernel=use_merge_kernel)
+            self._merge_operands = tuple(self._put(o) for o in operands)
         elif use_merge_kernel:
             def merge_fresh(fleet, mask):
                 return fleet_merge_masked_kernel(fleet, topology, mask, ridge=ridge)
@@ -304,7 +349,8 @@ class FleetRuntime:
             def merge_fresh(fleet, mask):
                 return _masked_merge_body(fleet, topology, mask, ridge)
 
-        self._merge_fresh = jax.jit(merge_fresh)
+        self._merge_fresh = self._jit(merge_fresh)
+        self._halo_bytes = halo_bytes(topology, self.shards, n_hidden, n_out)
 
         # ---- hardened merge boundary: faults in, robustness out ----
         # One compile-once closure owns the whole payload boundary of an
@@ -405,6 +451,21 @@ class FleetRuntime:
     def n_devices(self) -> int:
         return self.det.n_devices
 
+    def _put(self, x, *, replicated: bool = False):
+        """A host value on the device: on a sharded fleet each chip gets
+        only its slice of a (D, ...) leading array (or, ``replicated``,
+        all of a scalar)."""
+        if self._by_device is None:
+            return jax.tree_util.tree_map(jnp.asarray, x)
+        return jax.device_put(x, self._replicated if replicated else self._by_device)
+
+    def _jit(self, fn):
+        """A tick program; on a sharded fleet every output stays laid out
+        by device range, so the next tick's inputs hit the same trace."""
+        if self._by_device is None:
+            return jax.jit(fn)
+        return jax.jit(fn, out_shardings=self._by_device)
+
     # ------------------------------------------------------------- tick loop
 
     def _phase(self, name: str, **attrs):
@@ -490,14 +551,14 @@ class FleetRuntime:
 
         # the window (and the tick's small operands) to the device,
         # fenced: nothing else is in flight at this point
-        with self._phase("tick.put"):
+        with self._phase("tick.put", shards=self.shards):
             operands = (
-                jnp.asarray(batch), jnp.asarray(self._post_merge),
-                jnp.asarray(self._merge_mask), jnp.asarray(served_np),
+                self._put(batch), self._put(self._post_merge, replicated=True),
+                self._put(self._merge_mask), self._put(served_np),
             )
             jax.block_until_ready(operands)
 
-        with self._phase("tick.ingest") as ingest:
+        with self._phase("tick.ingest", shards=self.shards) as ingest:
             self.states, self.det, losses, drifted, fresh = self._ingest_detect(
                 self.states, self.det, *operands
             )
@@ -539,8 +600,8 @@ class FleetRuntime:
         robust_scores = None
         nonfinite = 0
         if decision.merge:
-            with self._phase("tick.merge") as merge:
-                mask_j = jnp.asarray(mask, jnp.float32)
+            with self._phase("tick.merge", halo_bytes=self._halo_bytes) as merge:
+                mask_j = self._put(mask.astype(np.float32))
                 if self._merge_boundary is not None:
                     shape = tuple(self._last_good.shape)
                     if injector is not None:
@@ -577,7 +638,8 @@ class FleetRuntime:
                     )
                     fence = (self.states, self._residual)
                 else:
-                    self.states = self._merge_fresh(self.states, mask_j)
+                    self.states = self._merge_fresh(
+                        self.states, mask_j, *self._merge_operands)
                     fence = self.states
                 # fence the FULL output pytree, not just states.beta — async
                 # dispatch would otherwise bill unfinished ring/residual/score
@@ -671,6 +733,7 @@ class FleetRuntime:
 
         if decision.merge:
             tel.merge_rounds.inc()
+            tel.merge_halo_bytes.inc(self._halo_bytes)
             split = self.governor.round_bytes_by_precision(
                 decision.participants, decision.fp_participants
             )
@@ -770,14 +833,14 @@ class FleetRuntime:
         as real ticks, so compile-once still holds afterwards."""
         d = self.n_devices
         f = int(self.states.params.alpha.shape[1])
-        batch = jnp.zeros((d, batch_size, f), jnp.float32)
-        none_served = jnp.zeros(d, bool)
+        batch = self._put(np.zeros((d, batch_size, f), np.float32))
+        none_served = self._put(np.zeros(d, bool))
         out = self._ingest_detect(
-            self.states, self.det, batch,
-            jnp.asarray(False), jnp.asarray(np.ones(d, bool)), none_served,
+            self.states, self.det, batch, self._put(False, replicated=True),
+            self._put(np.ones(d, bool)), none_served,
         )
         jax.block_until_ready(out)
-        mask = jnp.zeros(d, jnp.float32)
+        mask = self._put(np.zeros(d, np.float32))
         if self._merge_boundary is not None:
             shape = tuple(self._last_good.shape)
             out = self._merge_boundary(
@@ -796,7 +859,7 @@ class FleetRuntime:
                 self.states, mask, jnp.zeros(d, bool), self._residual
             )
         else:
-            out = self._merge_fresh(self.states, mask)
+            out = self._merge_fresh(self.states, mask, *self._merge_operands)
         jax.block_until_ready(out)
 
     # ------------------------------------------------------------ durability

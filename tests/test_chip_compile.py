@@ -155,7 +155,7 @@ def test_sharded_merge_factors_untransposed(v5e_2x2, topology):
     from jax.sharding import NamedSharding, PartitionSpec
 
     from repro.fleet import ring, star
-    from repro.fleet.sharded import _sharded_merge_program
+    from repro.fleet.sharded import sharded_merge
     from repro.launch.mesh import make_mesh
 
     n, nh, act, d = WIDTHS["har"]
@@ -167,9 +167,9 @@ def test_sharded_merge_factors_untransposed(v5e_2x2, topology):
         beta=s(d, nh, n), p=s(d, nh, nh), activation=act,
     )
     topo = {"star": star, "ring": lambda d: ring(d, hops=2)}[topology](d)
-    fn, operands = _sharded_merge_program(topo, mesh, ("data",), 1e-3)
+    fn, operands = sharded_merge(topo, mesh, ("data",), 1e-3, kernel=False)
     operands = [_shape(sh, o.shape, o.dtype) for o in operands]
-    text = fn.lower(states, *operands).compile().as_text()
+    text = jax.jit(fn).lower(states, _shape(sh, (d,)), *operands).compile().as_text()
     layouts = re.findall(
         r"\{([0-9,]+):[^}]*\} custom-call\([^)]*\), "
         r'custom_call_target="Cholesky"', text,
@@ -178,3 +178,49 @@ def test_sharded_merge_factors_untransposed(v5e_2x2, topology):
     for lay in layouts:
         dims = [int(x) for x in lay.split(",")]
         assert dims[:2] == [len(dims) - 1, len(dims) - 2], layouts
+
+
+@pytest.mark.parametrize("program", ["ingest", "ring", "star"])
+def test_sharded_tick_compiles(v5e_2x2, program):
+    """The tick programs of a har fleet laid out over the four chips
+    compile with every Pallas call inside ``shard_map`` (none is left to
+    the automatic partitioner, which refuses Mosaic kernels), and the
+    merges cross chips by a halo ``collective-permute`` (ring) or an
+    ``all-reduce`` of the cluster partials (star)."""
+    from jax.sharding import NamedSharding, PartitionSpec
+
+    from repro.fleet import ring, star
+    from repro.fleet.sharded import sharded_ingest, sharded_merge
+    from repro.launch.mesh import make_mesh
+    from repro.runtime.detector import DetectorConfig, init_detector
+    from repro.runtime.runtime import ingest_detect_program
+
+    n, nh, act, _ = WIDTHS["har"]
+    d = 4 * 256
+    mesh = make_mesh((4,), ("fleet",), devices=v5e_2x2.devices[:4])
+    sh = NamedSharding(mesh, PartitionSpec("fleet"))
+    s = lambda *shape, dtype=jnp.float32: _shape(sh, shape, dtype)  # noqa: E731
+    states = OSELMState(
+        params=SLFNParams(alpha=s(d, n, nh), bias=s(d, nh)),
+        beta=s(d, nh, n), p=s(d, nh, nh), activation=act,
+    )
+    if program == "ingest":
+        fn = ingest_detect_program(sharded_ingest(
+            mesh, ("fleet",), kernel=True, backend="pallas", interpret=False,
+        ), DetectorConfig())
+        det = jax.tree.map(lambda x: s(*x.shape, dtype=x.dtype), init_detector(d))
+        args = (states, det, s(d, 32, n),
+                _shape(NamedSharding(mesh, PartitionSpec()), (), bool),
+                s(d, dtype=bool), s(d, dtype=bool))
+        kernel, collective = "fleet_ingest_kernel", None
+    else:
+        topo = ring(d, hops=2) if program == "ring" else star(d)
+        fn, operands = sharded_merge(topo, mesh, ("fleet",), 1e-3, kernel=True,
+                                     interpret=False)
+        args = (states, s(d), *[s(*o.shape, dtype=o.dtype) for o in operands])
+        kernel, collective = {"ring": ("banded_merge_solve", "collective-permute"),
+                              "star": ("_masked_segment_sum_mix_call", "all-reduce")}[program]
+    compiled = jax.jit(fn, out_shardings=sh).lower(*args).compile()
+    _native(compiled, kernel)
+    if collective is not None:
+        assert collective in compiled.as_text()

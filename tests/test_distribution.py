@@ -196,7 +196,7 @@ _SHARDED_FLEET_SCRIPT = r"""
 import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
 import jax, jax.numpy as jnp, numpy as np
-from repro.fleet import (init_fleet, fleet_train, fleet_merge, fleet_merge_sharded,
+from repro.fleet import (init_fleet, fleet_train, fleet_merge, sharded_merge,
                          star, hierarchical, all_to_all)
 from repro.launch.sharding import shard_fleet
 
@@ -212,7 +212,8 @@ worst = 0.0
 for topo in (all_to_all(D), star(D), hierarchical(D, 4),
              hierarchical(D, 4, head_exchange=False)):
     ref = fleet_merge(fleet, topo, ridge=1e-3)
-    got = fleet_merge_sharded(fleet_s, topo, mesh, ("data",), ridge=1e-3)
+    fn, operands = sharded_merge(topo, mesh, ("data",), 1e-3, kernel=False)
+    got = jax.jit(fn)(fleet_s, np.ones(D, np.float32), *operands)
     worst = max(worst, float(jnp.max(jnp.abs(np.asarray(got.beta) - np.asarray(ref.beta)))))
 print("RESULT", worst)
 assert worst < 1e-4
@@ -237,8 +238,8 @@ _SHARDED_TRAIN_SCRIPT = r"""
 import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
 import jax, jax.numpy as jnp, numpy as np
-from repro.fleet import (init_fleet, fleet_train, fleet_train_sharded,
-                         fleet_merge, fleet_merge_sharded, ring)
+from repro.fleet import (init_fleet, fleet_train, fleet_merge, ring,
+                         sharded_ingest, sharded_merge)
 from repro.launch.sharding import shard_fleet
 
 from repro.launch.mesh import make_mesh
@@ -253,23 +254,23 @@ ref = fleet_train(fleet, streams)
 fleet_s = shard_fleet(fleet, mesh)
 worst = 0.0
 # per-shard ingest (no collectives), scan and fused-kernel paths
-for kw in (dict(), dict(kernel=True, backend="xla"),
+for kw in (dict(kernel=False), dict(kernel=True, backend="xla"),
            dict(kernel=True, backend="pallas", interpret=True)):
-    got = fleet_train_sharded(fleet_s, streams, mesh, ("data",), **kw)
+    got, _ = jax.jit(sharded_ingest(mesh, ("data",), **kw))(fleet_s, streams)
     worst = max(worst, float(jnp.max(jnp.abs(
         np.asarray(got.beta) - np.asarray(ref.beta)))))
 
 # open-ring halo-exchange merge across the 8 real shards (hops < D/8
 # stays within adjacent shards; hops == D/8 == 4 is the edge case)
-trained_s = fleet_train_sharded(fleet_s, streams, mesh, ("data",))
+trained_s, _ = jax.jit(sharded_ingest(mesh, ("data",), kernel=False))(fleet_s, streams)
 for hops in (1, 2, 4):
     mref = fleet_merge(ref, ring(D, hops=hops), ridge=1e-3)
-    mgot = fleet_merge_sharded(trained_s, ring(D, hops=hops), mesh, ("data",),
-                               ridge=1e-3)
+    fn, _ = sharded_merge(ring(D, hops=hops), mesh, ("data",), 1e-3, kernel=False)
+    mgot = jax.jit(fn)(trained_s, np.ones(D, np.float32))
     worst = max(worst, float(jnp.max(jnp.abs(
         np.asarray(mgot.beta) - np.asarray(mref.beta)))))
 try:  # a band wider than a shard straddles non-adjacent shards
-    fleet_merge_sharded(trained_s, ring(D, hops=5), mesh, ("data",))
+    sharded_merge(ring(D, hops=5), mesh, ("data",), 1e-3, kernel=False)
     raise SystemExit("expected halo hops validation to fire")
 except ValueError as e:
     assert "halo" in str(e), e

@@ -28,12 +28,12 @@ from repro.core import (
 )
 from repro.fleet import (
     fleet_merge,
-    fleet_merge_sharded,
     fleet_train,
     fleet_train_rounds,
-    fleet_train_sharded,
     init_fleet,
     ring,
+    sharded_ingest,
+    sharded_merge,
     star,
 )
 from repro.fleet.fleet import _fleet_train
@@ -203,7 +203,7 @@ def test_fleet_ingest_dispatcher_validates_backend():
         fleet_ingest(fleet, _window(d=4), backend="cuda")
 
 
-def test_fleet_train_sharded_single_shard_matches_unsharded():
+def test_sharded_ingest_single_shard_matches_unsharded():
     """shard_map'd ingest over a 1-shard mesh == fleet_train, both the
     scan and kernel paths (the 8-real-shard equality lives in
     tests/test_distribution.py as a subprocess test)."""
@@ -213,12 +213,16 @@ def test_fleet_train_sharded_single_shard_matches_unsharded():
     mesh = jax.make_mesh((1,), ("data",))
     fleet_s = shard_fleet(fleet, mesh)
     ref = fleet_train(fleet, win)
-    got = fleet_train_sharded(fleet_s, win, mesh, ("data",))
+    _, ref_losses = fleet_ingest(fleet, win, backend="xla")
+    got, losses = jax.jit(sharded_ingest(mesh, ("data",), kernel=False))(fleet_s, win)
     _assert_state_close(got, ref, rtol=1e-5, atol=1e-6)
-    got_k = fleet_train_sharded(
-        fleet_s, win, mesh, ("data",), kernel=True, backend="xla"
-    )
+    np.testing.assert_allclose(np.asarray(losses), np.asarray(ref_losses),
+                               rtol=1e-5, atol=1e-6)
+    got_k, losses_k = jax.jit(sharded_ingest(
+        mesh, ("data",), kernel=True, backend="xla"))(fleet_s, win)
     _assert_state_close(got_k, ref, rtol=2e-4, atol=2e-5)
+    np.testing.assert_allclose(np.asarray(losses_k), np.asarray(ref_losses),
+                               rtol=1e-5, atol=1e-6)
 
 
 def test_halo_ring_merge_single_shard_matches_fleet_merge():
@@ -234,9 +238,9 @@ def test_halo_ring_merge_single_shard_matches_fleet_merge():
     # (w[-0:] is the WHOLE shard block, not an empty halo)
     for hops in (0, 1, 2):
         ref = fleet_merge(fleet, ring(d, hops=hops), ridge=RIDGE)
-        got = fleet_merge_sharded(
-            fleet_s, ring(d, hops=hops), mesh, ("data",), ridge=RIDGE
-        )
+        fn, operands = sharded_merge(ring(d, hops=hops), mesh, ("data",), RIDGE,
+                                     kernel=False)
+        got = jax.jit(fn)(fleet_s, np.ones(d, np.float32), *operands)
         _assert_state_close(got, ref, rtol=1e-4, atol=1e-5)
 
 
@@ -341,24 +345,22 @@ def test_ingest_rejects_per_device_bases():
     fleet_train(stacked, win)
 
 
-def test_fleet_train_sharded_compile_once():
-    """The sharded ingest is a serve-loop hot path: repeated calls with
-    the same (mesh, axes, kernel, backend) reuse ONE jitted callable
-    instead of re-tracing per call."""
-    from repro.fleet.sharded import _SHARDED_JIT_CACHE
+def test_sharded_ingest_compile_once():
+    """The sharded ingest is the tick's hot path: jitted once with its
+    outputs laid out as its inputs (as ``FleetRuntime`` does), repeated
+    ticks reuse ONE trace instead of re-tracing per call."""
+    from jax.sharding import NamedSharding, PartitionSpec
 
     d = 8
     fleet = _fleet(d=d)
     mesh = jax.make_mesh((1,), ("data",))
     fleet_s = shard_fleet(fleet, mesh)
-    _SHARDED_JIT_CACHE.clear()
+    ingest = jax.jit(sharded_ingest(mesh, ("data",), kernel=False),
+                     out_shardings=NamedSharding(mesh, PartitionSpec("data")))
     sizes = []
     for seed in (2, 3, 4, 5):
-        fleet_s = fleet_train_sharded(
-            fleet_s, _window(d=d, seed=seed), mesh, ("data",)
-        )
-        assert len(_SHARDED_JIT_CACHE) == 1  # one callable, not one per call
-        sizes.append(next(iter(_SHARDED_JIT_CACHE.values()))._cache_size())
+        fleet_s, _ = ingest(fleet_s, _window(d=d, seed=seed))
+        sizes.append(ingest._cache_size())
     # the device_put input and the jit-output sharding may compile once
     # each; after that the trace count must be FLAT across ticks
     assert sizes[-1] == sizes[1] <= 2, sizes

@@ -15,13 +15,13 @@ from repro.fleet import (
     fleet_from_uv,
     fleet_merge,
     fleet_merge_kernel,
-    fleet_merge_sharded,
     fleet_to_uv,
     fleet_train,
     fleet_train_rounds,
     hierarchical,
     init_fleet,
     ring,
+    sharded_merge,
     star,
 )
 from repro.core import UV
@@ -152,6 +152,25 @@ def test_banded_merge_solve_fuses_mix_and_solve(hops):
     )
 
 
+@pytest.mark.parametrize("hops,lo,n_local", [(0, 2, 4), (1, 0, 4), (2, 3, 5), (2, D_ODD - 4, 4)])
+def test_banded_merge_solve_halo_block_equals_wrapped_centre(hops, lo, n_local):
+    """The halo form on one shard's block extended by ``hops`` rows each
+    side (wrapping round the ring) solves exactly the wrapped kernel's
+    rows of that block."""
+    dn, n, m = D_ODD, R_ODD, 23
+    h = _rand(8, (dn, 5 * n, n))
+    w = jnp.concatenate(
+        [jnp.einsum("dkn,dkm->dnm", h, h), _rand(9, (dn, n, m))], axis=2)
+    ext = jnp.take(w, np.arange(lo - hops, lo + n_local + hops) % dn, axis=0)
+    p, beta = banded_merge_solve(ext, hops, ridge=RIDGE, halo=True, interpret=True)
+    p_all, beta_all = banded_merge_solve(w, hops, ridge=RIDGE, interpret=True)
+    rows = np.arange(lo, lo + n_local) % dn
+    np.testing.assert_array_equal(np.asarray(p), np.asarray(p_all)[rows])
+    np.testing.assert_array_equal(np.asarray(beta), np.asarray(beta_all)[rows])
+    with pytest.raises(ValueError, match="no centre rows"):
+        banded_merge_solve(ext[:2 * hops], hops, ridge=RIDGE, halo=True, interpret=True)
+
+
 @pytest.mark.parametrize("topo_name", sorted(TOPO_FNS))
 def test_fleet_merge_kernel_matches_fleet_merge(small_fleet, topo_name):
     """End-to-end: the Pallas merge (mix kernels + fused solve) equals
@@ -223,7 +242,7 @@ def test_fleet_train_rounds_scan_matches_python_loop(small_fleet):
     )
 
 
-def test_fleet_merge_sharded_single_shard(small_fleet):
+def test_sharded_merge_single_shard(small_fleet):
     """psum-of-segment-sums merge on a 1-shard mesh equals fleet_merge
     for every cluster-wise-constant topology; the open ring now takes
     the ppermute halo-exchange path (its 1-shard degenerate form is the
@@ -239,7 +258,8 @@ def test_fleet_merge_sharded_single_shard(small_fleet):
                  hierarchical(d, 3, head_exchange=False), ring(d, hops=d // 2),
                  ring(d, hops=1), ring(d, hops=2)):
         ref = fleet_merge(fleet, topo, ridge=RIDGE)
-        got = fleet_merge_sharded(fleet_s, topo, mesh, ("data",), ridge=RIDGE)
+        fn, operands = sharded_merge(topo, mesh, ("data",), RIDGE, kernel=False)
+        got = jax.jit(fn)(fleet_s, np.ones(d, np.float32), *operands)
         np.testing.assert_allclose(
             np.asarray(got.beta), np.asarray(ref.beta), rtol=1e-4, atol=1e-5
         )
@@ -250,4 +270,4 @@ def test_fleet_merge_sharded_single_shard(small_fleet):
     m[0, 1] = m[1, 0] = 1.0  # sparse custom mask: not cluster-wise constant
     custom = Topology(name="custom", n_devices=d, kind="dense", matrix=m)
     with pytest.raises(NotImplementedError, match="neighbor sets"):
-        fleet_merge_sharded(fleet_s, custom, mesh, ("data",))
+        sharded_merge(custom, mesh, ("data",), RIDGE, kernel=False)
